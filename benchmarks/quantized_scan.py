@@ -92,7 +92,7 @@ def run(emit):
     cap = int(eng.cfg.capacity)
     rk = min(cap, RERANK * K)
     autotune.autotune_pq_adc_qbuf(cap, PQ_M, int(eng.cfg.pq_ks), rk,
-                                  candidates=(64, 128))
+                                  candidates=(64, 128), impl="interpret")
 
     results = {}
     for label, tier in (("f32", "f32"), ("adc", "pq")):

@@ -52,6 +52,9 @@ def main() -> None:
                     help="capture a jax.profiler trace of the whole run into "
                          "this directory (TensorBoard profile plugin)")
     args = ap.parse_args()
+    from repro.launch import compile_cache
+
+    compile_cache.enable(pathlib.Path(__file__).resolve().parents[1])
     only = {s for s in args.only.split(",") if s}
     unknown = only - {tag for tag, _ in SUITES}
     if unknown:  # a typo'd --only must not pass vacuously in CI

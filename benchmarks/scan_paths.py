@@ -111,8 +111,9 @@ def run(emit):
     # interpret path below bakes the winners in; sweeps land in the payload
     cap = int(eng.cfg.capacity)
     rk = min(cap, RERANK * K)
-    autotune.autotune_l2_qbuf(cap, DIM, K, candidates=(128, 256))
-    autotune.autotune_pq_adc_qbuf(cap, PQ_M, PQ_KS, rk, candidates=(64, 128))
+    autotune.autotune_l2_qbuf(cap, DIM, K, candidates=(128, 256), impl="interpret")
+    autotune.autotune_pq_adc_qbuf(cap, PQ_M, PQ_KS, rk, candidates=(64, 128),
+                                  impl="interpret")
     # stage-1 staged-operand accounting per tier: the compact plane + qbuf
     # indices the scalar-prefetch kernels stage vs the retired per-slot
     # host expansion (NQ=128 is already a pow2 jit bucket → q_row = NQ)

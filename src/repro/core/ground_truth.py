@@ -16,7 +16,7 @@ import numpy as np
 def _knn_block(q: jax.Array, base: jax.Array, k: int):
     d2 = (
         jnp.sum(q * q, axis=-1, keepdims=True)
-        - 2.0 * q @ base.T
+        - 2.0 * jnp.dot(q, base.T, precision=jax.lax.Precision.HIGHEST)
         + jnp.sum(base * base, axis=-1)[None, :]
     )
     neg, idx = jax.lax.top_k(-d2, k)
@@ -39,16 +39,13 @@ def exact_knn(queries: np.ndarray, base: np.ndarray, k: int, *, batch: int = 102
         out_i.append(np.asarray(i))
     dists, ids = np.concatenate(out_d), np.concatenate(out_i)
     if exclude_self:
-        # drop the first column where it is a self match (distance ~ 0)
-        keep_d = np.empty((len(q), k), np.float32)
-        keep_i = np.empty((len(q), k), np.int32)
-        for r in range(len(q)):
-            cols = [c for c in range(kk) if dists[r, c] > 1e-9][:k]
-            if len(cols) < k:  # degenerate duplicates; pad from the front
-                cols = list(range(1, k + 1))
-            keep_d[r] = dists[r, cols]
-            keep_i[r] = ids[r, cols]
-        return keep_d, keep_i
+        # keep the first k columns that are not self matches (distance ~ 0);
+        # rows with fewer (degenerate duplicates) keep columns 1..k
+        far = dists > 1e-9
+        cols = np.argsort(~far, axis=1, kind="stable")[:, :k]
+        cols = np.where((far.sum(1) >= k)[:, None], cols, np.arange(1, k + 1))
+        return (np.take_along_axis(dists, cols, 1).astype(np.float32),
+                np.take_along_axis(ids, cols, 1).astype(np.int32))
     return dists, ids
 
 

@@ -18,6 +18,10 @@ import jax.numpy as jnp
 
 from repro.kernels import ops as kops
 
+# f32 distances at full precision: a default f32 dot on the TPU rounds its
+# operands to bf16, which moves assignments and the probing model's inputs
+_EXACT = jax.lax.Precision.HIGHEST
+
 
 class KMeansState(NamedTuple):
     centroids: jax.Array  # [B, d] f32
@@ -54,7 +58,7 @@ def assign_points(x: jax.Array, centroids: jax.Array, *, use_kernel: bool = Fals
         return kops.kmeans_assign(x, centroids)
     d2 = (
         jnp.sum(x * x, axis=-1, keepdims=True)
-        - 2.0 * x @ centroids.T
+        - 2.0 * jnp.dot(x, centroids.T, precision=_EXACT)
         + jnp.sum(centroids * centroids, axis=-1)[None, :]
     )
     assign = jnp.argmin(d2, axis=-1).astype(jnp.int32)
@@ -90,6 +94,6 @@ def centroid_distances(q: jax.Array, centroids: jax.Array) -> jax.Array:
     """Query→centroid squared L2 distances `I` (probing-model input). [Q, B]."""
     return (
         jnp.sum(q * q, axis=-1, keepdims=True)
-        - 2.0 * q @ centroids.T
+        - 2.0 * jnp.dot(q, centroids.T, precision=_EXACT)
         + jnp.sum(centroids * centroids, axis=-1)[None, :]
     )

@@ -55,12 +55,14 @@ def build_store(
     *,
     capacity: Optional[int] = None,
     extra: Optional[tuple[np.ndarray, np.ndarray, np.ndarray]] = None,
+    align: int = 1,
 ) -> PartitionStore:
     """Build padded lists host-side (numpy; runs once at index build).
 
     ``extra`` = (vectors, ids, assign) replica rows appended by the redundancy
     strategy (paper §3.3); replicas share the id of the original point so the
-    merge step dedups naturally.
+    merge step dedups naturally. Without an explicit ``capacity`` it is the
+    largest partition rounded up to a multiple of ``align``.
     """
     b = centroids.shape[0]
     xs, xid, xa = [x], [ids], [assign]
@@ -75,7 +77,8 @@ def build_store(
     a_all = np.concatenate(xa, 0)
 
     counts = np.bincount(a_all, minlength=b)
-    cap = int(capacity if capacity is not None else max(1, counts.max()))
+    cap = int(capacity if capacity is not None
+              else -(-max(1, counts.max()) // align) * align)
     d = x.shape[1]
     vec = np.full((b, cap, d), 1e6, np.float32)  # far-away padding
     pid = np.full((b, cap), PAD_ID, np.int32)

@@ -56,7 +56,8 @@ def encode(pq: PQCodebook, x: np.ndarray, *, batch: int = 8192) -> np.ndarray:
         xb = xb.reshape(xb.shape[0], pq.m, d_sub)
         d2 = (
             jnp.sum(xb * xb, -1)[..., None]
-            - 2.0 * jnp.einsum("nmd,mkd->nmk", xb, pq.codebooks)
+            - 2.0 * jnp.einsum("nmd,mkd->nmk", xb, pq.codebooks,
+                               precision=jax.lax.Precision.HIGHEST)
             + jnp.sum(pq.codebooks * pq.codebooks, -1)[None]
         )
         return jnp.argmin(d2, -1).astype(jnp.int32)
@@ -90,7 +91,8 @@ def adc_lut_raw(codebooks: jax.Array, q: jax.Array) -> jax.Array:
     qs = q.reshape(q.shape[0], codebooks.shape[0], -1)
     return (
         jnp.sum(qs * qs, -1)[..., None]
-        - 2.0 * jnp.einsum("qmd,mkd->qmk", qs, codebooks)
+        - 2.0 * jnp.einsum("qmd,mkd->qmk", qs, codebooks,
+                           precision=jax.lax.Precision.HIGHEST)
         + jnp.sum(codebooks * codebooks, -1)[None]
     )
 
@@ -135,7 +137,8 @@ def adc_distances(pq: PQCodebook, q: jax.Array, codes: jax.Array) -> jax.Array:
 def residual_query_offsets(centroids: jax.Array, q: jax.Array) -> jax.Array:
     """off[q, b] = ‖c_b‖² − 2⟨q, c_b⟩ — the per-(query, partition) scalar of
     the residual ADC identity above. Equals ‖q − c_b‖² − ‖q‖²."""
-    return jnp.sum(centroids * centroids, -1)[None, :] - 2.0 * q @ centroids.T
+    return (jnp.sum(centroids * centroids, -1)[None, :]
+            - 2.0 * jnp.dot(q, centroids.T, precision=jax.lax.Precision.HIGHEST))
 
 
 def residual_cross_terms(pq: PQCodebook, centroids_per_row: np.ndarray,

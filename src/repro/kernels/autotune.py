@@ -9,6 +9,10 @@ the store, caches the winner per *store shape* (kernel, cap, operand dims, k
 — deliberately NOT b_loc/q_cap, which vary per pow2 batch bucket), and keeps
 an auditable record of every sweep for the bench JSON.
 
+``impl=None`` sweeps the backend's own kernels (``ops.default_impl``):
+Mosaic on a TPU. Pass ``impl="interpret"`` to exercise the sweep on a CPU;
+its timings are interpreter seconds and say nothing about the chip.
+
 Timing happens eagerly (outside jit) — benches and engines call
 ``autotune_*`` up front; the ops wrappers then do a Python-level cache lookup
 at trace time, so compiled steps bake the tile in. A step compiled before a
@@ -84,7 +88,7 @@ def _sweep(key: tuple, run_one, candidates: tuple[int, ...]) -> int:
 
 
 def autotune_pq_adc_qbuf(cap: int, m: int, ks: int, k: int, *,
-                         impl: str = "interpret",
+                         impl: str | None = None,
                          candidates: tuple[int, ...] = (64, 128, 256),
                          b_loc: int = 4, q_cap: int = 8,
                          q_row: int = 16, seed: int = 0) -> int:
@@ -113,7 +117,7 @@ def autotune_pq_adc_qbuf(cap: int, m: int, ks: int, k: int, *,
 
 
 def autotune_l2_qbuf(cap: int, d: int, k: int, *,
-                     impl: str = "interpret",
+                     impl: str | None = None,
                      candidates: tuple[int, ...] = (128, 256, 512),
                      b_loc: int = 4, q_cap: int = 8,
                      q_row: int = 16, seed: int = 0) -> int:

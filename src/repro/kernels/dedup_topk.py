@@ -7,18 +7,22 @@ the global top-k. The host evaluation engine used to do this with per-query
 Python set-loops; this kernel is the vectorized primitive that replaces them
 (and plugs the serving engine's missing dedup).
 
-Algorithm (sort-based, no hash tables — TPU/XLA friendly):
+Algorithm (k rounds of extraction, no sort and no hash table — plain
+reductions and selects, which the TPU lowers):
   1. remap invalid entries (id < 0 padding, non-finite distance = masked-out
-     partition) to an id sentinel that sorts last;
-  2. sort each row by (id, dist) lexicographically — a bitonic network here,
-     two stable argsorts in the jnp reference (ref.dedup_topk_ref);
-  3. first-occurrence mask: after the sort every duplicate id is adjacent and
-     the best (smallest-distance) copy comes first; kill the rest;
-  4. top-k by distance over the survivors.
+     partition) to an id sentinel at distance BIG;
+  2. each round takes the smallest remaining distance, breaking exact ties by
+     the smallest id, and retires every entry carrying that id — so an id is
+     emitted once, with its best distance, in (dist, id) order: exactly the
+     sort-by-(id, dist), keep-first, top-k-by-dist result of the jnp reference
+     (ref.dedup_topk_ref).
 
-Grid: (Q_tiles,) — the pool axis stays fully resident in VMEM so the bitonic
-network runs on-chip per query tile. Pool width must be a power of two
-(ops.py pads). VMEM per step ≈ 2·TQ·P·4 B (TQ=8, P=8192 → 512 KiB).
+Pools wider than one chunk (the serve step's b_loc·k pool is ~10⁵ wide) are
+merged in passes: dedup top-k per chunk, then the same kernel over the chunk
+winners. That is exact — an id in the global top-k is beaten by fewer than k
+distinct ids anywhere, so also within the chunk that holds its best copy.
+Grid: (Q_tiles, chunks); VMEM per step ≈ 2·2·TQ·chunk·4 B (TQ=8, chunk=2048 →
+256 KiB).
 """
 from __future__ import annotations
 
@@ -29,9 +33,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from repro.kernels._util import DEAD, lane_width, pad_dim, round_up
+
 PAD_ID = -1            # matches repro.core.partitions.PAD_ID
 BIG = 1e30             # finite distance sentinel (inf arithmetic is unsafe on VPU)
 ID_SENTINEL = 2**30    # id sentinel: sorts after every real id
+CHUNK = 2048           # pool lanes per grid step: an [8, 2048] f32 block is 16 vregs
 
 
 def dedup_topk_np(dists: np.ndarray, ids: np.ndarray, k: int):
@@ -42,8 +49,7 @@ def dedup_topk_np(dists: np.ndarray, ids: np.ndarray, k: int):
     high 32 bits are the id, the low 32 the IEEE-754 total-order image of the
     float32 distance (sign bit set for non-negative floats, bitwise-NOT for
     negative ones — a monotone uint32 map incl. ±0/inf/nan). Sorting the key
-    groups ids with the best distance first, exactly like the lexicographic
-    bitonic network in the Pallas kernel.
+    groups ids with the best distance first, like the jnp reference.
     """
     q, p = dists.shape
     d = np.ascontiguousarray(dists, dtype=np.float32)
@@ -63,7 +69,7 @@ def dedup_topk_np(dists: np.ndarray, ids: np.ndarray, k: int):
     d3 = np.where(keep, d2, np.inf)
     # final selection orders by (dist, id) — swap the key halves so distance
     # leads and ids break exact-distance ties deterministically (matches the
-    # jnp ref / bitonic kernel, which inherit this from the grouped sort)
+    # jnp ref / Pallas kernel)
     fkey = np.where(keep, (k2 << np.uint64(32)) | (k2 >> np.uint64(32)),
                     np.uint64(0xFFFFFFFFFFFFFFFF))
     kk = min(k, p)
@@ -81,85 +87,75 @@ def dedup_topk_np(dists: np.ndarray, ids: np.ndarray, k: int):
     return out_d, out_i
 
 
-def _lex_le(id_a, d_a, id_b, d_b):
-    """Lexicographic (id, dist) <=."""
-    return (id_a < id_b) | ((id_a == id_b) & (d_a <= d_b))
-
-
-def _bitonic_sort_by_id_dist(ids, d):
-    """Ascending (id, dist) bitonic sort along the last axis (power-of-two P).
-
-    The compare-exchange partner (index XOR 2^t) is materialized by reshaping
-    to [..., P/(2^(t+1)), 2, 2^t] and swapping the middle halves — no gathers.
-    Static Python loops: the O(log² P) network unrolls at trace time.
-    """
-    q, p = ids.shape
-    n_stage = p.bit_length() - 1
-    for s in range(1, n_stage + 1):          # merge blocks of size 2^s
-        for t in range(s - 1, -1, -1):       # partner distance 2^t
-            j = 1 << t
-            i4 = ids.reshape(q, p // (2 * j), 2, j)
-            d4 = d.reshape(q, p // (2 * j), 2, j)
-            id_lo, id_hi = i4[:, :, 0, :], i4[:, :, 1, :]
-            d_lo, d_hi = d4[:, :, 0, :], d4[:, :, 1, :]
-            # ascending iff bit s of the flat index is 0; the flat index is
-            # blk·2^(t+1) + h·2^t + w, so bit s == bit (s-t-1) of blk
-            blk = jax.lax.broadcasted_iota(jnp.int32, id_lo.shape, 1)
-            asc = ((blk >> (s - t - 1)) & 1) == 0
-            keep = _lex_le(id_lo, d_lo, id_hi, d_hi) == asc
-            ids = jnp.stack(
-                [jnp.where(keep, id_lo, id_hi), jnp.where(keep, id_hi, id_lo)], axis=2
-            ).reshape(q, p)
-            d = jnp.stack(
-                [jnp.where(keep, d_lo, d_hi), jnp.where(keep, d_hi, d_lo)], axis=2
-            ).reshape(q, p)
-    return ids, d
-
-
 def _dedup_topk_kernel(d_ref, i_ref, od_ref, oi_ref, *, k: int):
     d = d_ref[...].astype(jnp.float32)
     ids = i_ref[...]
     invalid = (ids < 0) | ~(d < BIG)          # padding, masked-out (inf), or nan
     ids = jnp.where(invalid, ID_SENTINEL, ids)
-    d = jnp.where(invalid, BIG, d)
-    ids, d = _bitonic_sort_by_id_dist(ids, d)
-    # adjacent-duplicate kill: the first copy of each id carries its best dist
-    prev = jnp.concatenate([jnp.full((ids.shape[0], 1), -2, ids.dtype), ids[:, :-1]], axis=1)
-    d = jnp.where((ids == prev) | (ids == ID_SENTINEL), BIG, d)
-    neg, pos = jax.lax.top_k(-d, k)
-    od = -neg
-    good = od < BIG
-    od_ref[...] = jnp.where(good, od, jnp.inf)
-    oi_ref[...] = jnp.where(good, jnp.take_along_axis(ids, pos, axis=1), PAD_ID)
+    key = jnp.where(invalid, -BIG, -d)        # max-extraction on -dist
+    rows, width = od_ref.shape
+    col = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 1)
+    no_id = jnp.iinfo(jnp.int32).max
+
+    def take(i, carry):
+        key, out_d, out_i = carry
+        best = jnp.max(key, axis=1, keepdims=True)
+        best_id = jnp.min(jnp.where(key == best, ids, no_id), axis=1, keepdims=True)
+        at = col == i
+        return (jnp.where(ids == best_id, DEAD, key),
+                jnp.where(at, -best, out_d), jnp.where(at, best_id, out_i))
+
+    init = (key, jnp.full((rows, width), BIG, jnp.float32),
+            jnp.full((rows, width), PAD_ID, jnp.int32))
+    _, out_d, out_i = jax.lax.fori_loop(0, k, take, init)
+    good = out_d < BIG
+    od_ref[...] = jnp.where(good, out_d, jnp.inf)
+    oi_ref[...] = jnp.where(good, out_i, PAD_ID)
+
+
+def _dedup_pass(dists, ids, k: int, tq: int, width: int, interpret: bool):
+    """Dedup top-k of every ``width``-wide chunk: [Q, n·width] → [Q, n·kp]."""
+    qn = dists.shape[0]
+    n_chunks = dists.shape[1] // width
+    kp = lane_width(k)
+    return pl.pallas_call(
+        functools.partial(_dedup_topk_kernel, k=k),
+        grid=(qn // tq, n_chunks),
+        in_specs=[
+            pl.BlockSpec((tq, width), lambda i, j: (i, j)),
+            pl.BlockSpec((tq, width), lambda i, j: (i, j)),
+        ],
+        out_specs=[
+            pl.BlockSpec((tq, kp), lambda i, j: (i, j)),
+            pl.BlockSpec((tq, kp), lambda i, j: (i, j)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((qn, n_chunks * kp), jnp.float32),
+            jax.ShapeDtypeStruct((qn, n_chunks * kp), jnp.int32),
+        ],
+        interpret=interpret,
+    )(dists, ids)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "tq", "interpret"))
 def dedup_topk(
-    dists: jax.Array,   # [Q, P] f32 — Q multiple of tq, P a power of two
+    dists: jax.Array,   # [Q, P] f32 — Q multiple of tq
     ids: jax.Array,     # [Q, P] i32, <0 = padding
     k: int,
     *,
     tq: int = 8,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     qn, p = dists.shape
-    assert qn % tq == 0 and p & (p - 1) == 0, (qn, tq, p)
-    assert 0 < k <= p, (k, p)
-    kernel = functools.partial(_dedup_topk_kernel, k=k)
-    return pl.pallas_call(
-        kernel,
-        grid=(qn // tq,),
-        in_specs=[
-            pl.BlockSpec((tq, p), lambda i: (i, 0)),
-            pl.BlockSpec((tq, p), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((tq, k), lambda i: (i, 0)),
-            pl.BlockSpec((tq, k), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((qn, k), jnp.float32),
-            jax.ShapeDtypeStruct((qn, k), jnp.int32),
-        ],
-        interpret=interpret,
-    )(dists, ids)
+    assert qn % tq == 0 and k > 0, (qn, tq, k)
+    # each pass must shrink the pool: a chunk holds at least two outputs' width
+    chunk = max(CHUNK, 2 * lane_width(k))
+    while True:
+        width = min(chunk, round_up(p, 128))
+        dists = pad_dim(dists, 1, width, jnp.inf)
+        ids = pad_dim(ids, 1, width, PAD_ID)
+        last = dists.shape[1] == width
+        dists, ids = _dedup_pass(dists, ids, k, tq, width, interpret)
+        if last:
+            return dists[:, :k], ids[:, :k]
+        p = dists.shape[1]

@@ -35,8 +35,10 @@ def _assign_kernel(x_ref, c_ref, oa_ref, od_ref, run_d, run_i, *, tb: int, n_bbl
                                     preferred_element_type=jnp.float32)
         + jnp.sum(c * c, axis=-1)[None, :]
     )  # [TN, TB]
-    blk_min = jnp.min(d2, axis=1)
-    blk_arg = jnp.argmin(d2, axis=1).astype(jnp.int32) + bb * tb
+    # argmin as min + first matching lane (no argmin lowering in-kernel)
+    blk_min = jnp.min(d2, axis=1, keepdims=True)                       # [TN, 1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, d2.shape, 1)
+    blk_arg = jnp.min(jnp.where(d2 == blk_min, lane, tb), axis=1, keepdims=True) + bb * tb
     better = blk_min < run_d[...]
     run_d[...] = jnp.where(better, blk_min, run_d[...])
     run_i[...] = jnp.where(better, blk_arg, run_i[...])
@@ -54,13 +56,15 @@ def kmeans_assign(
     *,
     tn: int = 512,
     tb: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     n, d = x.shape
     b = centroids.shape[0]
     assert n % tn == 0 and b % tb == 0, (n, tn, b, tb)
     n_bblocks = b // tb
     kernel = functools.partial(_assign_kernel, tb=tb, n_bblocks=n_bblocks)
+    # per-point outputs are [N, 1] columns: 1-D blocks do not match the
+    # TPU's vector layout
     assign, mind = pl.pallas_call(
         kernel,
         grid=(n // tn, n_bblocks),
@@ -69,17 +73,17 @@ def kmeans_assign(
             pl.BlockSpec((tb, d), lambda i, j: (j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((tn,), lambda i, j: (i,)),
-            pl.BlockSpec((tn,), lambda i, j: (i,)),
+            pl.BlockSpec((tn, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((tn, 1), lambda i, j: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n,), jnp.int32),
-            jax.ShapeDtypeStruct((n,), jnp.float32),
+            jax.ShapeDtypeStruct((n, 1), jnp.int32),
+            jax.ShapeDtypeStruct((n, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((tn,), jnp.float32),
-            pltpu.VMEM((tn,), jnp.int32),
+            pltpu.VMEM((tn, 1), jnp.float32),
+            pltpu.VMEM((tn, 1), jnp.int32),
         ],
         interpret=interpret,
     )(x, centroids)
-    return assign, mind
+    return assign[:, 0], mind[:, 0]

@@ -25,7 +25,22 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._util import NEG_BIG
+from repro.kernels._util import (NEG_BIG, flush_running, lane_width,
+                                 merge_running, running_init)
+
+
+def neg_sq_l2(q, c, cid):
+    """-‖q − c‖² tile ``[TQ, TC]`` on the MXU, padded candidates (cid < 0)
+    at NEG_BIG. HIGHEST precision: an f32 dot on the TPU otherwise rounds its
+    operands to bf16, and this scan claims exact f32 distances."""
+    d2 = (
+        2.0 * jax.lax.dot_general(q, c, (((1,), (1,)), ((), ())),
+                                  preferred_element_type=jnp.float32,
+                                  precision=jax.lax.Precision.HIGHEST)
+        - jnp.sum(q * q, axis=-1, keepdims=True)
+        - jnp.sum(c * c, axis=-1)[None, :]
+    )
+    return jnp.where(cid < 0, NEG_BIG, d2)
 
 
 def _l2_topk_kernel(q_ref, c_ref, cid_ref, od_ref, oi_ref, run_d, run_i, *, k: int, n_cblocks: int):
@@ -34,35 +49,15 @@ def _l2_topk_kernel(q_ref, c_ref, cid_ref, od_ref, oi_ref, run_d, run_i, *, k: i
 
     @pl.when(cb == 0)
     def _init():
-        run_d[...] = jnp.full_like(run_d, NEG_BIG)
-        run_i[...] = jnp.full_like(run_i, -1)
+        run_d[...], run_i[...] = running_init(*run_d.shape)
 
-    q = q_ref[...].astype(jnp.float32)          # [TQ, d]
-    c = c_ref[...].astype(jnp.float32)          # [TC, d]
-    cid = cid_ref[...]                          # [TC] int32
-
-    # negated squared L2 so the running reduce is a plain max-top-k
-    d2 = (
-        2.0 * jax.lax.dot_general(q, c, (((1,), (1,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-        - jnp.sum(q * q, axis=-1, keepdims=True)
-        - jnp.sum(c * c, axis=-1)[None, :]
-    )  # [TQ, TC] = -dist²
-    d2 = jnp.where(cid[None, :] < 0, NEG_BIG, d2)  # mask padded candidates
-
-    merged_d = jnp.concatenate([run_d[...], d2], axis=1)                 # [TQ, k+TC]
-    merged_i = jnp.concatenate([run_i[...], jnp.broadcast_to(cid[None, :], d2.shape)], axis=1)
-    top_d, pos = jax.lax.top_k(merged_d, k)
-    run_d[...] = top_d
-    run_i[...] = jnp.take_along_axis(merged_i, pos, axis=1)
+    d2 = neg_sq_l2(q_ref[...].astype(jnp.float32), c_ref[...].astype(jnp.float32),
+                   cid_ref[...])                                       # [TQ, TC]
+    run_d[...], run_i[...] = merge_running(run_d[...], run_i[...], d2, cid_ref[...], k)
 
     @pl.when(cb == n_cblocks - 1)
     def _flush():
-        # back to positive squared distances; slots never filled by a valid
-        # candidate flush as inf/-1 exactly like the jnp oracle
-        invalid = run_d[...] <= NEG_BIG / 2
-        od_ref[...] = jnp.where(invalid, jnp.inf, -run_d[...])
-        oi_ref[...] = jnp.where(invalid, -1, run_i[...])
+        od_ref[...], oi_ref[...] = flush_running(run_d[...], run_i[...])
 
 
 @functools.partial(jax.jit, static_argnames=("k", "tq", "tc", "interpret"))
@@ -74,35 +69,37 @@ def l2_topk(
     *,
     tq: int = 256,
     tc: int = 256,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     qn, d = q.shape
     cn = cands.shape[0]
     assert qn % tq == 0 and cn % tc == 0, (qn, tq, cn, tc)
     n_cblocks = cn // tc
+    kp = lane_width(k)
     kernel = functools.partial(_l2_topk_kernel, k=k, n_cblocks=n_cblocks)
-    return pl.pallas_call(
+    od, oi = pl.pallas_call(
         kernel,
         grid=(qn // tq, n_cblocks),
         in_specs=[
             pl.BlockSpec((tq, d), lambda i, j: (i, 0)),
             pl.BlockSpec((tc, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((tc,), lambda i, j: (j,)),
+            pl.BlockSpec((1, tc), lambda i, j: (0, j)),
         ],
         out_specs=[
-            pl.BlockSpec((tq, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((tq, k), lambda i, j: (i, 0)),
+            pl.BlockSpec((tq, kp), lambda i, j: (i, 0)),
+            pl.BlockSpec((tq, kp), lambda i, j: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((qn, k), jnp.float32),
-            jax.ShapeDtypeStruct((qn, k), jnp.int32),
+            jax.ShapeDtypeStruct((qn, kp), jnp.float32),
+            jax.ShapeDtypeStruct((qn, kp), jnp.int32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((tq, k), jnp.float32),
-            pltpu.VMEM((tq, k), jnp.int32),
+            pltpu.VMEM((tq, kp), jnp.float32),
+            pltpu.VMEM((tq, kp), jnp.int32),
         ],
         interpret=interpret,
-    )(q, cands, cand_ids)
+    )(q, cands, cand_ids.reshape(1, cn))
+    return od[:, :k], oi[:, :k]
 
 
 def _l2_topk_batched_kernel(q_ref, c_ref, cid_ref, od_ref, oi_ref, run_d, run_i,
@@ -114,32 +111,15 @@ def _l2_topk_batched_kernel(q_ref, c_ref, cid_ref, od_ref, oi_ref, run_d, run_i,
 
     @pl.when(cb == 0)
     def _init():
-        run_d[...] = jnp.full_like(run_d, NEG_BIG)
-        run_i[...] = jnp.full_like(run_i, -1)
+        run_d[...], run_i[...] = running_init(*run_d.shape)
 
-    q = q_ref[0].astype(jnp.float32)            # [TQ, d]
-    c = c_ref[0].astype(jnp.float32)            # [TC, d]
-    cid = cid_ref[0]                            # [TC] int32
-
-    d2 = (
-        2.0 * jax.lax.dot_general(q, c, (((1,), (1,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-        - jnp.sum(q * q, axis=-1, keepdims=True)
-        - jnp.sum(c * c, axis=-1)[None, :]
-    )  # [TQ, TC] = -dist²
-    d2 = jnp.where(cid[None, :] < 0, NEG_BIG, d2)
-
-    merged_d = jnp.concatenate([run_d[...], d2], axis=1)
-    merged_i = jnp.concatenate([run_i[...], jnp.broadcast_to(cid[None, :], d2.shape)], axis=1)
-    top_d, pos = jax.lax.top_k(merged_d, k)
-    run_d[...] = top_d
-    run_i[...] = jnp.take_along_axis(merged_i, pos, axis=1)
+    cid = cid_ref[0]                                                   # [1, TC]
+    d2 = neg_sq_l2(q_ref[0].astype(jnp.float32), c_ref[0].astype(jnp.float32), cid)
+    run_d[...], run_i[...] = merge_running(run_d[...], run_i[...], d2, cid, k)
 
     @pl.when(cb == n_cblocks - 1)
     def _flush():
-        invalid = run_d[...] <= NEG_BIG / 2
-        od_ref[0] = jnp.where(invalid, jnp.inf, -run_d[...])
-        oi_ref[0] = jnp.where(invalid, -1, run_i[...])
+        od_ref[0], oi_ref[0] = flush_running(run_d[...], run_i[...])
 
 
 @functools.partial(jax.jit, static_argnames=("k", "tq", "tc", "interpret"))
@@ -151,7 +131,7 @@ def l2_topk_batched(
     *,
     tq: int = 256,
     tc: int = 256,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     """Grid-batched l2_topk: scans all B (query-bucket, candidate-set) pairs in
     ONE pallas launch — the serve step's per-partition scan shape."""
@@ -159,29 +139,31 @@ def l2_topk_batched(
     cn = cands.shape[1]
     assert qn % tq == 0 and cn % tc == 0, (qn, tq, cn, tc)
     n_cblocks = cn // tc
+    kp = lane_width(k)
     kernel = functools.partial(_l2_topk_batched_kernel, k=k, n_cblocks=n_cblocks)
-    return pl.pallas_call(
+    od, oi = pl.pallas_call(
         kernel,
         grid=(bn, qn // tq, n_cblocks),
         in_specs=[
             pl.BlockSpec((1, tq, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, tc, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, tc), lambda b, i, j: (b, j)),
+            pl.BlockSpec((1, 1, tc), lambda b, i, j: (b, 0, j)),
         ],
         out_specs=[
-            pl.BlockSpec((1, tq, k), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, tq, k), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, tq, kp), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, tq, kp), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bn, qn, k), jnp.float32),
-            jax.ShapeDtypeStruct((bn, qn, k), jnp.int32),
+            jax.ShapeDtypeStruct((bn, qn, kp), jnp.float32),
+            jax.ShapeDtypeStruct((bn, qn, kp), jnp.int32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((tq, k), jnp.float32),
-            pltpu.VMEM((tq, k), jnp.int32),
+            pltpu.VMEM((tq, kp), jnp.float32),
+            pltpu.VMEM((tq, kp), jnp.int32),
         ],
         interpret=interpret,
-    )(q, cands, cand_ids)
+    )(q, cands, cand_ids.reshape(bn, 1, cn))
+    return od[..., :k], oi[..., :k]
 
 
 def _l2_topk_qbuf_kernel(qb_ref, q_hbm, vec_hbm, cid_ref, od_ref, oi_ref,
@@ -194,7 +176,9 @@ def _l2_topk_qbuf_kernel(qb_ref, q_hbm, vec_hbm, cid_ref, od_ref, oi_ref,
     kernel, same arithmetic order, so distances stay bit-identical."""
     b = pl.program_id(0)
 
-    # phase 1: gather this bucket's S query rows from the compact plane
+    # phase 1: gather this bucket's S query rows from the compact plane (rows
+    # on an untiled leading axis: a one-row slice of a tiled axis is not a
+    # legal DMA window on the TPU)
     def gather(s, carry):
         cp = pltpu.make_async_copy(q_hbm.at[qb_ref[b, s]], q_s.at[s], sem_q)
         cp.start()
@@ -202,7 +186,7 @@ def _l2_topk_qbuf_kernel(qb_ref, q_hbm, vec_hbm, cid_ref, od_ref, oi_ref,
         return carry
 
     jax.lax.fori_loop(0, n_slots, gather, 0)
-    q = q_s[...].astype(jnp.float32)            # [S, d]
+    q = q_s[...].reshape(n_slots, -1).astype(jnp.float32)   # [S, d]
 
     # phase 2: stream candidate blocks through a 2-deep VMEM ring
     def copy_block(j, slot):
@@ -212,7 +196,6 @@ def _l2_topk_qbuf_kernel(qb_ref, q_hbm, vec_hbm, cid_ref, od_ref, oi_ref,
     copy_block(0, 0).start()
 
     def body(j, carry):
-        run_d, run_i = carry
         slot = jax.lax.rem(j, 2)
 
         @pl.when(j + 1 < n_cblocks)
@@ -221,26 +204,11 @@ def _l2_topk_qbuf_kernel(qb_ref, q_hbm, vec_hbm, cid_ref, od_ref, oi_ref,
 
         copy_block(j, slot).wait()
         c = vbuf[slot].astype(jnp.float32)      # [TC, d]
-        cid = cid_ref[0, pl.ds(j * tc, tc)]     # [TC] int32, -1 = padding
-        d2 = (
-            2.0 * jax.lax.dot_general(q, c, (((1,), (1,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
-            - jnp.sum(q * q, axis=-1, keepdims=True)
-            - jnp.sum(c * c, axis=-1)[None, :]
-        )  # [S, TC] = -dist²
-        d2 = jnp.where(cid[None, :] < 0, NEG_BIG, d2)
-        merged_d = jnp.concatenate([run_d, d2], axis=1)
-        merged_i = jnp.concatenate(
-            [run_i, jnp.broadcast_to(cid[None, :], d2.shape)], axis=1)
-        top_d, pos = jax.lax.top_k(merged_d, k)
-        return top_d, jnp.take_along_axis(merged_i, pos, axis=1)
+        cid = cid_ref[0, :, pl.ds(pl.multiple_of(j * tc, tc), tc)]   # [1, TC]
+        return merge_running(*carry, neg_sq_l2(q, c, cid), cid, k)
 
-    init = (jnp.full((n_slots, k), NEG_BIG, jnp.float32),
-            jnp.full((n_slots, k), -1, jnp.int32))
-    run_d, run_i = jax.lax.fori_loop(0, n_cblocks, body, init)
-    invalid = run_d <= NEG_BIG / 2
-    od_ref[0] = jnp.where(invalid, jnp.inf, -run_d)
-    oi_ref[0] = jnp.where(invalid, -1, run_i)
+    init = running_init(n_slots, od_ref.shape[-1])
+    od_ref[0], oi_ref[0] = flush_running(*jax.lax.fori_loop(0, n_cblocks, body, init))
 
 
 @functools.partial(jax.jit, static_argnames=("k", "tc", "interpret"))
@@ -252,7 +220,7 @@ def l2_topk_qbuf(
     k: int,
     *,
     tc: int = 256,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     """Dispatch-buffer form of ``l2_topk_batched``: takes the compact
     ``q_pad`` plane plus ``qbuf`` indices instead of a host-expanded
@@ -264,22 +232,23 @@ def l2_topk_qbuf(
     cn, d = cands.shape[1], cands.shape[2]
     assert cn % tc == 0, (cn, tc)
     n_cblocks = cn // tc
+    kp = lane_width(k)
     kernel = functools.partial(_l2_topk_qbuf_kernel, k=k, tc=tc,
                                n_cblocks=n_cblocks, n_slots=n_slots)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(bn,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),         # q_pad stays in HBM
-            pl.BlockSpec(memory_space=pltpu.ANY),         # cands stay in HBM
-            pl.BlockSpec((1, cn), lambda b, qb: (b, 0)),  # cand_ids
+            pl.BlockSpec(memory_space=pl.ANY),              # q_pad stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),              # cands stay in HBM
+            pl.BlockSpec((1, 1, cn), lambda b, qb: (b, 0, 0)),  # cand_ids
         ],
         out_specs=[
-            pl.BlockSpec((1, n_slots, k), lambda b, qb: (b, 0, 0)),
-            pl.BlockSpec((1, n_slots, k), lambda b, qb: (b, 0, 0)),
+            pl.BlockSpec((1, n_slots, kp), lambda b, qb: (b, 0, 0)),
+            pl.BlockSpec((1, n_slots, kp), lambda b, qb: (b, 0, 0)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((n_slots, d), q_pad.dtype),
+            pltpu.VMEM((n_slots, 1, d), q_pad.dtype),
             pltpu.VMEM((2, tc, d), cands.dtype),
             pltpu.SemaphoreType.DMA(()),
             pltpu.SemaphoreType.DMA((2,)),
@@ -289,9 +258,9 @@ def l2_topk_qbuf(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((bn, n_slots, k), jnp.float32),
-            jax.ShapeDtypeStruct((bn, n_slots, k), jnp.int32),
+            jax.ShapeDtypeStruct((bn, n_slots, kp), jnp.float32),
+            jax.ShapeDtypeStruct((bn, n_slots, kp), jnp.int32),
         ],
         interpret=interpret,
-    )(qbuf, q_pad, cands, cand_ids)
-    return od, oi
+    )(qbuf, q_pad.reshape(q_pad.shape[0], 1, d), cands, cand_ids.reshape(bn, 1, cn))
+    return od[..., :k], oi[..., :k]

@@ -1,28 +1,45 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On this container (CPU) kernels run in interpret mode for validation; the
-jnp reference path (`impl="ref"`) is the fast CPU fallback used by benches.
-On a real TPU backend, `impl="pallas"` compiles the kernels natively.
+``impl`` picks the backend of every wrapper:
+  * ``"pallas"``    — the kernels compiled with Mosaic. TPU only: off a TPU the
+                      kernel's lowering raises, it never falls back;
+  * ``"interpret"`` — the same kernels through the Pallas interpreter, the
+                      explicit choice for checking them on a CPU;
+  * ``"ref"``       — the jnp oracles (kernels/ref.py);
+  * ``None``        — ``default_impl()``: pallas on a TPU, ref elsewhere.
 
 All wrappers pad inputs to tile multiples and strip padding from outputs, so
 callers never worry about alignment.
 """
 from __future__ import annotations
 
-import functools
+import math
 
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import ref as _ref
+from repro.kernels import autotune as _autotune
 from repro.kernels import dedup_topk as _dd
+from repro.kernels import kmeans_assign as _km
 from repro.kernels import l2_topk as _l2
 from repro.kernels import pq_adc as _adc
-from repro.kernels import kmeans_assign as _km
-
-
-from repro.kernels import autotune as _autotune
+from repro.kernels import ref as _ref
 from repro.kernels._util import pad_dim as _pad_dim, pad_rows as _pad_rows
+
+# Partition capacities are kept whole 128-lane tiles of slots: the qbuf scans
+# then pick a candidate-block tile that divides the capacity and stream the
+# store in place. Any other capacity is padded to a tile multiple, a copy of
+# the whole store on every call.
+SLOT_ALIGN = 128
+
+
+def _slot_tile(tile: int, n: int) -> int:
+    """Candidate-block tile for an ``n``-slot axis: the widest whole-lane
+    tile dividing both ``tile`` and ``n`` when ``n`` is lane-aligned (no
+    padded copy), else ``tile`` capped at ``n``."""
+    if n % SLOT_ALIGN == 0 and tile % SLOT_ALIGN == 0:
+        return math.gcd(tile, n)
+    return min(tile, max(8, n))
 
 
 def default_impl() -> str:
@@ -31,12 +48,20 @@ def default_impl() -> str:
     return "pallas" if jax.default_backend() == "tpu" else "ref"
 
 
+def _interpret(impl: str) -> bool:
+    """Kernel-level ``interpret`` flag for a non-ref impl."""
+    if impl not in ("pallas", "interpret"):
+        raise ValueError(f"unknown kernel impl {impl!r}; expected 'ref', "
+                         "'pallas' or 'interpret'")
+    return impl == "interpret"
+
+
 def l2_topk(q, cands, cand_ids, k: int, *, impl: str | None = None, tq: int = 256, tc: int = 256):
     """Top-k nearest candidates per query. Handles arbitrary Q/C via padding."""
     impl = impl or default_impl()
     if impl == "ref":
         return _ref.l2_topk_ref(q, cands, cand_ids, k)
-    interpret = impl == "interpret" or jax.default_backend() != "tpu"
+    interpret = _interpret(impl)
     qn = q.shape[0]
     tq_eff = min(tq, max(8, qn))
     qp = _pad_rows(q, tq_eff, 0.0)
@@ -59,7 +84,7 @@ def l2_topk_batched(q, cands, cand_ids, k: int, *, impl: str | None = None,
     impl = impl or default_impl()
     if impl == "ref":
         return _ref.l2_topk_batched_ref(q, cands, cand_ids, k)
-    interpret = impl == "interpret" or jax.default_backend() != "tpu"
+    interpret = _interpret(impl)
     _, qn, _ = q.shape
     cn = cands.shape[1]
     tq_eff = min(tq, max(8, qn))
@@ -83,11 +108,11 @@ def l2_topk_qbuf(q_pad, qbuf, cands, cand_ids, k: int, *,
     qbuf = qbuf.astype(jnp.int32)
     if impl == "ref":
         return _ref.l2_topk_qbuf_ref(q_pad, qbuf, cands, cand_ids, k)
-    interpret = impl == "interpret" or jax.default_backend() != "tpu"
+    interpret = _interpret(impl)
     cn, d = cands.shape[1], cands.shape[2]
     if tc is None:
         tc = _autotune.lookup(_autotune.l2_key(cn, d, k))
-    tc_eff = min(tc, max(8, cn))
+    tc_eff = _slot_tile(tc, cn)
     cp = _pad_dim(cands, 1, tc_eff, 0.0)
     ip = _pad_dim(cand_ids.astype(jnp.int32), 1, tc_eff, -1)
     return _l2.l2_topk_qbuf(q_pad, qbuf, cp, ip, k, tc=tc_eff,
@@ -107,13 +132,13 @@ def pq_adc_topk_qbuf(lut_pad, qbuf, codes, cand_ids, k: int, *, cand_off=None,
     if impl == "ref":
         return _ref.pq_adc_topk_qbuf_ref(lut_pad, qbuf, codes, cand_ids, k,
                                          cand_off=cand_off, q_off=q_off)
-    interpret = impl == "interpret" or jax.default_backend() != "tpu"
+    interpret = _interpret(impl)
     bn, n_slots = qbuf.shape
     nn, m = codes.shape[1], codes.shape[2]
     ks = lut_pad.shape[2]
     if tn is None:
         tn = _autotune.lookup(_autotune.pq_adc_key(nn, m, ks, k))
-    tn_eff = min(tn, max(8, nn))
+    tn_eff = _slot_tile(tn, nn)
     cp = _pad_dim(codes.astype(jnp.int32), 1, tn_eff, 0)
     ip = _pad_dim(cand_ids.astype(jnp.int32), 1, tn_eff, -1)
     if cand_off is None:
@@ -138,26 +163,21 @@ def pq_adc_topk_batched(lut, codes, cand_ids, k: int, *, cand_off=None,
                                             cand_off=cand_off, q_off=q_off)
     return _adc.pq_adc_topk_batched(lut, codes, cand_ids, k, cand_off=cand_off,
                                     q_off=q_off, tq=tq, tn=tn,
-                                    interpret=True if impl == "interpret" else None)
+                                    interpret=_interpret(impl))
 
 
 def dedup_topk(dists, ids, k: int, *, impl: str | None = None, tq: int = 8):
     """Replica-aware merge: collapse duplicate ids to their best distance, then
-    exact global top-k. Handles arbitrary Q/P via row + power-of-two padding."""
+    exact global top-k. Handles arbitrary Q/P (rows padded to ``tq``, the pool
+    to whole chunks inside the kernel wrapper)."""
     impl = impl or default_impl()
     if impl == "ref":
         return _ref.dedup_topk_ref(dists, ids, k)
-    interpret = impl == "interpret" or jax.default_backend() != "tpu"
-    qn, p = dists.shape
-    p2 = max(2, 1 << (max(p, k) - 1).bit_length())
-    dists = dists.astype(jnp.float32)
-    ids = ids.astype(jnp.int32)
-    if p2 > p:  # pad the pool with invalid entries
-        dists = jnp.concatenate([dists, jnp.full((qn, p2 - p), jnp.inf, jnp.float32)], axis=1)
-        ids = jnp.concatenate([ids, jnp.full((qn, p2 - p), -1, jnp.int32)], axis=1)
+    interpret = _interpret(impl)
+    qn = dists.shape[0]
     tq_eff = min(tq, max(8, qn))
-    dp = _pad_rows(dists, tq_eff, jnp.inf)
-    ip = _pad_rows(ids, tq_eff, -1)
+    dp = _pad_rows(dists.astype(jnp.float32), tq_eff, jnp.inf)
+    ip = _pad_rows(ids.astype(jnp.int32), tq_eff, -1)
     d, i = _dd.dedup_topk(dp, ip, k, tq=tq_eff, interpret=interpret)
     return d[:qn], i[:qn]
 
@@ -167,9 +187,7 @@ def pq_adc(lut, codes, *, impl: str | None = None, tq: int = 128, tn: int = 128)
     impl = impl or default_impl()
     if impl == "ref":
         return _ref.pq_adc_ref(lut, codes)
-    # interpret=None defers to the kernel's own backend detection (one policy)
-    return _adc.pq_adc(lut, codes, tq=tq, tn=tn,
-                       interpret=True if impl == "interpret" else None)
+    return _adc.pq_adc(lut, codes, tq=tq, tn=tn, interpret=_interpret(impl))
 
 
 def pq_adc_topk(lut, codes, cand_ids, k: int, *, cand_off=None, q_off=None,
@@ -184,8 +202,7 @@ def pq_adc_topk(lut, codes, cand_ids, k: int, *, cand_off=None, q_off=None,
         return _ref.pq_adc_topk_ref(lut, codes, cand_ids, k,
                                     cand_off=cand_off, q_off=q_off)
     return _adc.pq_adc_topk(lut, codes, cand_ids, k, cand_off=cand_off,
-                            q_off=q_off, tq=tq, tn=tn,
-                            interpret=True if impl == "interpret" else None)
+                            q_off=q_off, tq=tq, tn=tn, interpret=_interpret(impl))
 
 
 def kmeans_assign(x, centroids, *, impl: str | None = None, tn: int = 512, tb: int = 128):
@@ -193,7 +210,7 @@ def kmeans_assign(x, centroids, *, impl: str | None = None, tn: int = 512, tb: i
     impl = impl or default_impl()
     if impl == "ref":
         return _ref.kmeans_assign_ref(x, centroids)
-    interpret = impl == "interpret" or jax.default_backend() != "tpu"
+    interpret = _interpret(impl)
     n, b = x.shape[0], centroids.shape[0]
     tn_eff = min(tn, max(8, n))
     tb_eff = min(tb, b)

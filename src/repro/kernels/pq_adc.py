@@ -21,13 +21,19 @@ Three entry points:
     codes stream through a double-buffered in-kernel pipeline.
 
 Tiling: grid = (Q_tiles, N_blocks); LUT tile [TQ, m·ks] stays in VMEM across
-the candidate scan, codes stream in as [TN, m] int blocks.
+the candidate scan, codes stream in as [m, TN] int blocks.
 VMEM per step ≈ TQ·m·ks + TN·m·ks (one-hot) + TQ·TN f32
 (TQ=128, TN=128, m=16, ks=256 → ~4.5 MB).
 
-Both wrappers pad Q/N to tile multiples internally (and strip the padding from
-outputs), and default ``interpret`` from the backend exactly like
-repro.kernels.ops: native compile on TPU, interpreter elsewhere.
+Layouts the TPU lowers: LUTs enter as ``[Q, m·ks]`` rows and codes as
+``[m, N]`` (one subspace per sublane, slots along lanes), so the one-hot tile
+of subspace j is a sublane compare ``[ks, TN]`` and the m tiles stack into one
+``[m·ks, TN]`` MXU operand — no 3-D reshape inside the kernel. Per-candidate
+operands ride as ``[1, N]`` rows and per-query offsets as ``[Q, 1]`` columns.
+
+The wrappers pad Q/N to tile multiples internally (and strip the padding from
+outputs). ``interpret=False`` compiles with Mosaic, which needs a TPU;
+``interpret=True`` is the explicit CPU choice.
 """
 from __future__ import annotations
 
@@ -38,24 +44,33 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._util import NEG_BIG, pad_dim, pad_rows as _pad_rows
+from repro.kernels._util import (NEG_BIG, flush_running, lane_width, merge_running,
+                                 pad_dim, pad_rows as _pad_rows, running_init)
 
 
-def _detect_interpret(interpret: bool | None) -> bool:
-    return jax.default_backend() != "tpu" if interpret is None else interpret
+def adc_scores(lut, codes_t, ks: int):
+    """ADC distances ``[S, T]`` of a LUT tile ``lut [S, m·ks]`` against a code
+    block ``codes_t [m, T]``: Σ_j lut[s, j·ks + codes_t[j, t]], as one MXU
+    contraction with the stacked one-hot ``[m·ks, T]``. HIGHEST precision
+    keeps the f32 LUT entries whole (the one-hot is exact in any dtype)."""
+    m, t = codes_t.shape
+    rows = jax.lax.broadcasted_iota(jnp.int32, (ks, t), 0)
+    onehot = jnp.concatenate(
+        [(codes_t[j:j + 1, :] == rows).astype(jnp.float32) for j in range(m)],
+        axis=0)                                                    # [m·ks, T]
+    return jax.lax.dot_general(lut, onehot, (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32,
+                               precision=jax.lax.Precision.HIGHEST)
+
+
+def _neg_adc(lut, codes_t, cid, coff, qoff, ks):
+    """-(ADC + q_off + cand_off), padded candidates (cid < 0) at NEG_BIG."""
+    d = adc_scores(lut, codes_t, ks) + qoff + coff
+    return jnp.where(cid < 0, NEG_BIG, -d)
 
 
 def _pq_adc_kernel(lut_ref, codes_ref, out_ref, *, ks: int):
-    lut = lut_ref[...]        # [TQ, m, ks] f32
-    codes = codes_ref[...]    # [TN, m] int32
-    onehot = jax.nn.one_hot(codes, ks, dtype=lut.dtype)        # [TN, m, ks]
-    # dist[q, n] = Σ_m Σ_k lut[q,m,k]·onehot[n,m,k]  — a dense MXU contraction
-    out_ref[...] = jax.lax.dot_general(
-        lut.reshape(lut.shape[0], -1),
-        onehot.reshape(onehot.shape[0], -1),
-        (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    out_ref[...] = adc_scores(lut_ref[...], codes_ref[...], ks)
 
 
 @functools.partial(jax.jit, static_argnames=("tq", "tn", "interpret"))
@@ -65,25 +80,24 @@ def pq_adc(
     *,
     tq: int = 128,
     tn: int = 128,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jax.Array:
     qn, m, ks = lut.shape
     n = codes.shape[0]
-    interpret = _detect_interpret(interpret)
     tq = min(tq, max(8, qn))
     tn = min(tn, max(8, n))
-    lp = _pad_rows(lut, tq, 0.0)
-    cp = _pad_rows(codes.astype(jnp.int32), tn, 0)
+    lp = _pad_rows(lut.reshape(qn, m * ks), tq, 0.0)
+    cp = pad_dim(codes.astype(jnp.int32).T, 1, tn, 0)
     kernel = functools.partial(_pq_adc_kernel, ks=ks)
     out = pl.pallas_call(
         kernel,
-        grid=(lp.shape[0] // tq, cp.shape[0] // tn),
+        grid=(lp.shape[0] // tq, cp.shape[1] // tn),
         in_specs=[
-            pl.BlockSpec((tq, m, ks), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((tn, m), lambda i, j: (j, 0)),
+            pl.BlockSpec((tq, m * ks), lambda i, j: (i, 0)),
+            pl.BlockSpec((m, tn), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((tq, tn), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((lp.shape[0], cp.shape[0]), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((lp.shape[0], cp.shape[1]), jnp.float32),
         interpret=interpret,
     )(lp, cp)
     return out[:qn, :n]
@@ -96,35 +110,16 @@ def _pq_adc_topk_kernel(lut_ref, codes_ref, cid_ref, coff_ref, qoff_ref,
 
     @pl.when(nb == 0)
     def _init():
-        run_d[...] = jnp.full_like(run_d, NEG_BIG)
-        run_i[...] = jnp.full_like(run_i, -1)
+        run_d[...], run_i[...] = running_init(*run_d.shape)
 
-    lut = lut_ref[...]        # [TQ, m, ks] f32
-    codes = codes_ref[...]    # [TN, m] int32
-    cid = cid_ref[...]        # [TN] int32, -1 = padding
-    coff = coff_ref[...]      # [TN] f32 per-candidate offset (residual cterm)
-    qoff = qoff_ref[...]      # [TQ] f32 per-query offset (residual ‖c‖²−2qc)
-    onehot = jax.nn.one_hot(codes, ks, dtype=lut.dtype)
-    d = jax.lax.dot_general(
-        lut.reshape(lut.shape[0], -1),
-        onehot.reshape(onehot.shape[0], -1),
-        (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # [TQ, TN]
-    d = d + qoff[:, None] + coff[None, :]
-    negd = jnp.where(cid[None, :] < 0, NEG_BIG, -d)
-    merged_d = jnp.concatenate([run_d[...], negd], axis=1)               # [TQ, k+TN]
-    merged_i = jnp.concatenate(
-        [run_i[...], jnp.broadcast_to(cid[None, :], negd.shape)], axis=1)
-    top_d, pos = jax.lax.top_k(merged_d, k)
-    run_d[...] = top_d
-    run_i[...] = jnp.take_along_axis(merged_i, pos, axis=1)
+    cid = cid_ref[...]                                             # [1, TN]
+    negd = _neg_adc(lut_ref[...], codes_ref[...], cid, coff_ref[...],
+                    qoff_ref[...], ks)                             # [TQ, TN]
+    run_d[...], run_i[...] = merge_running(run_d[...], run_i[...], negd, cid, k)
 
     @pl.when(nb == n_nblocks - 1)
     def _flush():
-        invalid = run_d[...] <= NEG_BIG / 2
-        od_ref[...] = jnp.where(invalid, jnp.inf, -run_d[...])
-        oi_ref[...] = jnp.where(invalid, -1, run_i[...])
+        od_ref[...], oi_ref[...] = flush_running(run_d[...], run_i[...])
 
 
 @functools.partial(jax.jit, static_argnames=("k", "tq", "tn", "interpret"))
@@ -138,7 +133,7 @@ def pq_adc_topk(
     q_off: jax.Array | None = None,     # [Q] f32 added per query
     tq: int = 128,
     tn: int = 128,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ):
     """Fused ADC scan + running top-k: ([Q, k] dists asc, [Q, k] ids).
 
@@ -148,45 +143,45 @@ def pq_adc_topk(
     the returned distances equal exact L2 to the reconstruction."""
     qn, m, ks = lut.shape
     n = codes.shape[0]
-    interpret = _detect_interpret(interpret)
     tq = min(tq, max(8, qn))
     tn = min(tn, max(8, n))
-    lp = _pad_rows(lut, tq, 0.0)
-    cp = _pad_rows(codes.astype(jnp.int32), tn, 0)
-    ip = _pad_rows(cand_ids.astype(jnp.int32), tn, -1)
+    kp = lane_width(k)
     if cand_off is None:
         cand_off = jnp.zeros((n,), jnp.float32)
     if q_off is None:
         q_off = jnp.zeros((qn,), jnp.float32)
-    cop = _pad_rows(cand_off.astype(jnp.float32), tn, 0.0)
-    qop = _pad_rows(q_off.astype(jnp.float32), tq, 0.0)
-    n_nblocks = cp.shape[0] // tn
+    lp = _pad_rows(lut.reshape(qn, m * ks), tq, 0.0)
+    cp = pad_dim(codes.astype(jnp.int32).T, 1, tn, 0)
+    ip = pad_dim(cand_ids.astype(jnp.int32)[None], 1, tn, -1)
+    cop = pad_dim(cand_off.astype(jnp.float32)[None], 1, tn, 0.0)
+    qop = _pad_rows(q_off.astype(jnp.float32)[:, None], tq, 0.0)
+    n_nblocks = cp.shape[1] // tn
     kernel = functools.partial(_pq_adc_topk_kernel, k=k, ks=ks, n_nblocks=n_nblocks)
     od, oi = pl.pallas_call(
         kernel,
         grid=(lp.shape[0] // tq, n_nblocks),
         in_specs=[
-            pl.BlockSpec((tq, m, ks), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((tn, m), lambda i, j: (j, 0)),
-            pl.BlockSpec((tn,), lambda i, j: (j,)),
-            pl.BlockSpec((tn,), lambda i, j: (j,)),
-            pl.BlockSpec((tq,), lambda i, j: (i,)),
+            pl.BlockSpec((tq, m * ks), lambda i, j: (i, 0)),
+            pl.BlockSpec((m, tn), lambda i, j: (0, j)),
+            pl.BlockSpec((1, tn), lambda i, j: (0, j)),
+            pl.BlockSpec((1, tn), lambda i, j: (0, j)),
+            pl.BlockSpec((tq, 1), lambda i, j: (i, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((tq, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((tq, k), lambda i, j: (i, 0)),
+            pl.BlockSpec((tq, kp), lambda i, j: (i, 0)),
+            pl.BlockSpec((tq, kp), lambda i, j: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((lp.shape[0], k), jnp.float32),
-            jax.ShapeDtypeStruct((lp.shape[0], k), jnp.int32),
+            jax.ShapeDtypeStruct((lp.shape[0], kp), jnp.float32),
+            jax.ShapeDtypeStruct((lp.shape[0], kp), jnp.int32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((tq, k), jnp.float32),
-            pltpu.VMEM((tq, k), jnp.int32),
+            pltpu.VMEM((tq, kp), jnp.float32),
+            pltpu.VMEM((tq, kp), jnp.int32),
         ],
         interpret=interpret,
     )(lp, cp, ip, cop, qop)
-    return od[:qn], oi[:qn]
+    return od[:qn, :k], oi[:qn, :k]
 
 
 def _pq_adc_topk_batched_kernel(lut_ref, codes_ref, cid_ref, coff_ref, qoff_ref,
@@ -198,35 +193,15 @@ def _pq_adc_topk_batched_kernel(lut_ref, codes_ref, cid_ref, coff_ref, qoff_ref,
 
     @pl.when(nb == 0)
     def _init():
-        run_d[...] = jnp.full_like(run_d, NEG_BIG)
-        run_i[...] = jnp.full_like(run_i, -1)
+        run_d[...], run_i[...] = running_init(*run_d.shape)
 
-    lut = lut_ref[0]          # [TQ, m, ks] f32
-    codes = codes_ref[0]      # [TN, m] int32
-    cid = cid_ref[0]          # [TN] int32, -1 = padding
-    coff = coff_ref[0]        # [TN] f32
-    qoff = qoff_ref[0]        # [TQ] f32
-    onehot = jax.nn.one_hot(codes, ks, dtype=lut.dtype)
-    d = jax.lax.dot_general(
-        lut.reshape(lut.shape[0], -1),
-        onehot.reshape(onehot.shape[0], -1),
-        (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # [TQ, TN]
-    d = d + qoff[:, None] + coff[None, :]
-    negd = jnp.where(cid[None, :] < 0, NEG_BIG, -d)
-    merged_d = jnp.concatenate([run_d[...], negd], axis=1)
-    merged_i = jnp.concatenate(
-        [run_i[...], jnp.broadcast_to(cid[None, :], negd.shape)], axis=1)
-    top_d, pos = jax.lax.top_k(merged_d, k)
-    run_d[...] = top_d
-    run_i[...] = jnp.take_along_axis(merged_i, pos, axis=1)
+    cid = cid_ref[0]                                               # [1, TN]
+    negd = _neg_adc(lut_ref[0], codes_ref[0], cid, coff_ref[0], qoff_ref[0], ks)
+    run_d[...], run_i[...] = merge_running(run_d[...], run_i[...], negd, cid, k)
 
     @pl.when(nb == n_nblocks - 1)
     def _flush():
-        invalid = run_d[...] <= NEG_BIG / 2
-        od_ref[0] = jnp.where(invalid, jnp.inf, -run_d[...])
-        oi_ref[0] = jnp.where(invalid, -1, run_i[...])
+        od_ref[0], oi_ref[0] = flush_running(run_d[...], run_i[...])
 
 
 @functools.partial(jax.jit, static_argnames=("k", "tq", "tn", "interpret"))
@@ -240,53 +215,53 @@ def pq_adc_topk_batched(
     q_off: jax.Array | None = None,     # [B, Q] f32 added per query
     tq: int = 128,
     tn: int = 128,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ):
     """Grid-batched pq_adc_topk: all B (query-bucket, code-block) pairs in ONE
     pallas launch — the quantized serve step's per-partition shortlist shape.
     Offsets carry the residual-PQ corrections exactly like the flat kernel."""
     bn, qn, m, ks = lut.shape
     n = codes.shape[1]
-    interpret = _detect_interpret(interpret)
     tq = min(tq, max(8, qn))
     tn = min(tn, max(8, n))
-    lp = pad_dim(lut, 1, tq, 0.0)
-    cp = pad_dim(codes.astype(jnp.int32), 1, tn, 0)
-    ip = pad_dim(cand_ids.astype(jnp.int32), 1, tn, -1)
+    kp = lane_width(k)
     if cand_off is None:
         cand_off = jnp.zeros((bn, n), jnp.float32)
     if q_off is None:
         q_off = jnp.zeros((bn, qn), jnp.float32)
-    cop = pad_dim(cand_off.astype(jnp.float32), 1, tn, 0.0)
-    qop = pad_dim(q_off.astype(jnp.float32), 1, tq, 0.0)
-    n_nblocks = cp.shape[1] // tn
+    lp = pad_dim(lut.reshape(bn, qn, m * ks), 1, tq, 0.0)
+    cp = pad_dim(codes.astype(jnp.int32).transpose(0, 2, 1), 2, tn, 0)
+    ip = pad_dim(cand_ids.astype(jnp.int32)[:, None], 2, tn, -1)
+    cop = pad_dim(cand_off.astype(jnp.float32)[:, None], 2, tn, 0.0)
+    qop = pad_dim(q_off.astype(jnp.float32)[..., None], 1, tq, 0.0)
+    n_nblocks = cp.shape[2] // tn
     kernel = functools.partial(_pq_adc_topk_batched_kernel, k=k, ks=ks,
                                n_nblocks=n_nblocks)
     od, oi = pl.pallas_call(
         kernel,
         grid=(bn, lp.shape[1] // tq, n_nblocks),
         in_specs=[
-            pl.BlockSpec((1, tq, m, ks), lambda b, i, j: (b, i, 0, 0)),
-            pl.BlockSpec((1, tn, m), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, tn), lambda b, i, j: (b, j)),
-            pl.BlockSpec((1, tn), lambda b, i, j: (b, j)),
-            pl.BlockSpec((1, tq), lambda b, i, j: (b, i)),
+            pl.BlockSpec((1, tq, m * ks), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, m, tn), lambda b, i, j: (b, 0, j)),
+            pl.BlockSpec((1, 1, tn), lambda b, i, j: (b, 0, j)),
+            pl.BlockSpec((1, 1, tn), lambda b, i, j: (b, 0, j)),
+            pl.BlockSpec((1, tq, 1), lambda b, i, j: (b, i, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, tq, k), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, tq, k), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, tq, kp), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, tq, kp), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bn, lp.shape[1], k), jnp.float32),
-            jax.ShapeDtypeStruct((bn, lp.shape[1], k), jnp.int32),
+            jax.ShapeDtypeStruct((bn, lp.shape[1], kp), jnp.float32),
+            jax.ShapeDtypeStruct((bn, lp.shape[1], kp), jnp.int32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((tq, k), jnp.float32),
-            pltpu.VMEM((tq, k), jnp.int32),
+            pltpu.VMEM((tq, kp), jnp.float32),
+            pltpu.VMEM((tq, kp), jnp.int32),
         ],
         interpret=interpret,
     )(lp, cp, ip, cop, qop)
-    return od[:, :qn], oi[:, :qn]
+    return od[:, :qn, :k], oi[:, :qn, :k]
 
 
 def _pq_adc_topk_qbuf_kernel(qb_ref, lut_hbm, codes_hbm, cid_ref, coff_ref,
@@ -299,7 +274,9 @@ def _pq_adc_topk_qbuf_kernel(qb_ref, lut_hbm, codes_hbm, cid_ref, coff_ref,
     1. scalar-prefetched LUT gather — ``qb_ref`` (SMEM) names each dispatch
        slot's query row; the rows are DMA'd one by one from the compact
        ``lut_pad`` plane in HBM into the ``lut_s`` VMEM scratch. Empty slots
-       (``q_row``) fetch the zero sentinel row.
+       (``q_row``) fetch the zero sentinel row. Rows sit on an untiled
+       leading axis (``[rows, 1, m·ks]``): a one-row slice of a tiled axis
+       is not a legal DMA window on the TPU.
     2. double-buffered candidate streaming — code blocks of ``tn`` slots are
        DMA'd into the 2-deep ``cbuf`` ring; block j+1's copy is in flight
        while block j feeds the one-hot MXU contraction and the running
@@ -316,16 +293,15 @@ def _pq_adc_topk_qbuf_kernel(qb_ref, lut_hbm, codes_hbm, cid_ref, coff_ref,
 
     jax.lax.fori_loop(0, n_slots, gather, 0)
     lut = lut_s[...].reshape(n_slots, -1)       # [S, m·ks] f32
-    qoff = qoff_ref[0]                          # [S] f32
+    qoff = qoff_ref[0]                          # [S, 1] f32
 
     def copy_block(j, slot):
-        return pltpu.make_async_copy(codes_hbm.at[b, pl.ds(j * tn, tn)],
+        return pltpu.make_async_copy(codes_hbm.at[b, :, pl.ds(j * tn, tn)],
                                      cbuf.at[slot], sem_codes.at[slot])
 
     copy_block(0, 0).start()
 
     def body(j, carry):
-        run_d, run_i = carry
         slot = jax.lax.rem(j, 2)
 
         @pl.when(j + 1 < n_nblocks)
@@ -333,29 +309,13 @@ def _pq_adc_topk_qbuf_kernel(qb_ref, lut_hbm, codes_hbm, cid_ref, coff_ref,
             copy_block(j + 1, jax.lax.rem(j + 1, 2)).start()
 
         copy_block(j, slot).wait()
-        codes = cbuf[slot]                      # [tn, m] int32
-        cid = cid_ref[0, pl.ds(j * tn, tn)]     # [tn] int32, -1 = padding
-        coff = coff_ref[0, pl.ds(j * tn, tn)]   # [tn] f32
-        onehot = jax.nn.one_hot(codes, ks, dtype=lut_s.dtype)
-        d = jax.lax.dot_general(
-            lut, onehot.reshape(onehot.shape[0], -1),
-            (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [S, tn]
-        d = d + qoff[:, None] + coff[None, :]
-        negd = jnp.where(cid[None, :] < 0, NEG_BIG, -d)
-        merged_d = jnp.concatenate([run_d, negd], axis=1)
-        merged_i = jnp.concatenate(
-            [run_i, jnp.broadcast_to(cid[None, :], negd.shape)], axis=1)
-        top_d, pos = jax.lax.top_k(merged_d, k)
-        return top_d, jnp.take_along_axis(merged_i, pos, axis=1)
+        blk = pl.ds(pl.multiple_of(j * tn, tn), tn)
+        cid = cid_ref[0, :, blk]                # [1, tn] int32, -1 = padding
+        negd = _neg_adc(lut, cbuf[slot], cid, coff_ref[0, :, blk], qoff, ks)
+        return merge_running(*carry, negd, cid, k)
 
-    init = (jnp.full((n_slots, k), NEG_BIG, jnp.float32),
-            jnp.full((n_slots, k), -1, jnp.int32))
-    run_d, run_i = jax.lax.fori_loop(0, n_nblocks, body, init)
-    invalid = run_d <= NEG_BIG / 2
-    od_ref[0] = jnp.where(invalid, jnp.inf, -run_d)
-    oi_ref[0] = jnp.where(invalid, -1, run_i)
+    init = running_init(n_slots, od_ref.shape[-1])
+    od_ref[0], oi_ref[0] = flush_running(*jax.lax.fori_loop(0, n_nblocks, body, init))
 
 
 @functools.partial(jax.jit, static_argnames=("k", "tn", "interpret"))
@@ -369,7 +329,7 @@ def pq_adc_topk_qbuf(
     cand_off: jax.Array,  # [B, N] f32 residual cterm plane (zeros when unused)
     q_off: jax.Array,     # [B, S] f32 per-slot residual offset (zeros when unused)
     tn: int = 128,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ):
     """Scalar-prefetch-gathered, streaming form of ``pq_adc_topk_batched``.
 
@@ -381,30 +341,30 @@ def pq_adc_topk_qbuf(
     (S·m·ks·4 bytes) — S is the dispatch q_cap, small by construction.
     """
     bn, n_slots = qbuf.shape
-    _, m, ks = lut_pad.shape
+    q_rows, m, ks = lut_pad.shape
     n = codes.shape[1]
     assert n % tn == 0, (n, tn)
-    interpret = _detect_interpret(interpret)
     n_nblocks = n // tn
+    kp = lane_width(k)
     kernel = functools.partial(_pq_adc_topk_qbuf_kernel, k=k, ks=ks, tn=tn,
                                n_nblocks=n_nblocks, n_slots=n_slots)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(bn,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),            # lut_pad (HBM)
-            pl.BlockSpec(memory_space=pltpu.ANY),            # codes (HBM)
-            pl.BlockSpec((1, n), lambda b, qb: (b, 0)),      # cand_ids
-            pl.BlockSpec((1, n), lambda b, qb: (b, 0)),      # cand_off
-            pl.BlockSpec((1, n_slots), lambda b, qb: (b, 0)),  # q_off
+            pl.BlockSpec(memory_space=pl.ANY),                     # lut_pad (HBM)
+            pl.BlockSpec(memory_space=pl.ANY),                     # codes (HBM)
+            pl.BlockSpec((1, 1, n), lambda b, qb: (b, 0, 0)),        # cand_ids
+            pl.BlockSpec((1, 1, n), lambda b, qb: (b, 0, 0)),        # cand_off
+            pl.BlockSpec((1, n_slots, 1), lambda b, qb: (b, 0, 0)),  # q_off
         ],
         out_specs=[
-            pl.BlockSpec((1, n_slots, k), lambda b, qb: (b, 0, 0)),
-            pl.BlockSpec((1, n_slots, k), lambda b, qb: (b, 0, 0)),
+            pl.BlockSpec((1, n_slots, kp), lambda b, qb: (b, 0, 0)),
+            pl.BlockSpec((1, n_slots, kp), lambda b, qb: (b, 0, 0)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((n_slots, m, ks), jnp.float32),  # gathered LUT rows
-            pltpu.VMEM((2, tn, m), jnp.int32),          # code stream ring
+            pltpu.VMEM((n_slots, 1, m * ks), jnp.float32),  # gathered LUT rows
+            pltpu.VMEM((2, m, tn), jnp.int32),           # code stream ring
             pltpu.SemaphoreType.DMA(()),
             pltpu.SemaphoreType.DMA((2,)),
         ],
@@ -413,9 +373,11 @@ def pq_adc_topk_qbuf(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((bn, n_slots, k), jnp.float32),
-            jax.ShapeDtypeStruct((bn, n_slots, k), jnp.int32),
+            jax.ShapeDtypeStruct((bn, n_slots, kp), jnp.float32),
+            jax.ShapeDtypeStruct((bn, n_slots, kp), jnp.int32),
         ],
         interpret=interpret,
-    )(qbuf, lut_pad, codes, cand_ids, cand_off, q_off)
-    return od, oi
+    )(qbuf, lut_pad.reshape(q_rows, 1, m * ks), codes.transpose(0, 2, 1),
+      cand_ids.reshape(bn, 1, n), cand_off.reshape(bn, 1, n),
+      q_off.reshape(bn, n_slots, 1))
+    return od[..., :k], oi[..., :k]

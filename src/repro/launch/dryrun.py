@@ -1,12 +1,14 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+# host devices only: every --all child would otherwise try to take the TPU
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run (deliverable e): lower + compile EVERY (arch × shape)
 cell on the production meshes — 16×16 single-pod and 2×16×16 multi-pod —
 recording memory analysis, HLO/analytic cost terms, and the collective
 schedule for the roofline (EXPERIMENTS.md §Dry-run / §Roofline).
 
-The XLA_FLAGS line above MUST precede every other import (jax locks the device
+The XLA_FLAGS / JAX_PLATFORMS lines above MUST precede every other import (jax locks the device
 count at first init). Run one cell:
 
     PYTHONPATH=src python -m repro.launch.dryrun --arch deepseek-coder-33b \

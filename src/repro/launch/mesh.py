@@ -4,7 +4,14 @@ Defined as functions so importing this module never touches jax device state.
 """
 from __future__ import annotations
 
-from repro.utils.compat import make_mesh
+import jax
+
+
+def make_mesh(shape, axis_names):
+    """``jax.make_mesh`` with Auto axes: shardings propagate through the
+    program, as the serve and train steps are written for."""
+    return jax.make_mesh(shape, axis_names,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -8,11 +8,13 @@ kill (DESIGN.md §5).
 from __future__ import annotations
 
 import argparse
+import pathlib
 import time
 
 import numpy as np
 
 from repro.data import make_vector_dataset
+from repro.launch import compile_cache
 from repro.launch.mesh import make_test_mesh
 from repro.serving import (
     BuildConfig,
@@ -62,6 +64,7 @@ def main():
                     help="stream host-side serving spans (repro.obs.trace) to "
                          "this JSON-lines file")
     args = ap.parse_args()
+    compile_cache.enable(pathlib.Path(__file__).resolve().parents[3])
     tier = args.tier
     if args.quantized or args.residual:
         tier = tiers.legacy_tier_name(args.quantized, args.residual)

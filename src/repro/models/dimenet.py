@@ -36,8 +36,6 @@ from repro.configs.base import GNNConfig
 from repro.models.api import ModelBundle, ShapeSpec, StepDef, adamw_state_pspecs, adamw_state_specs, sds
 from repro.train import optimizer as opt
 
-from repro.utils.compat import shard_map
-
 
 # ----------------------------------------------------------------- bases
 
@@ -86,8 +84,8 @@ def sharded_edge_gather(edge_feat, idx, mesh):
         return jax.lax.psum(part, axes)
 
     spec = P(axes if len(axes) > 1 else axes[0])
-    return shard_map(f, mesh=mesh, in_specs=(P(spec[0], None), spec), out_specs=P(spec[0], None),
-                     check_vma=False)(edge_feat, idx)
+    return jax.shard_map(f, mesh=mesh, in_specs=(P(spec[0], None), spec), out_specs=P(spec[0], None),
+                         check_vma=False)(edge_feat, idx)
 
 
 def sharded_segment_to_nodes(edge_feat, dst, n_nodes: int, mesh):
@@ -99,8 +97,8 @@ def sharded_segment_to_nodes(edge_feat, dst, n_nodes: int, mesh):
         return jax.lax.psum(part, axes)
 
     spec = axes if len(axes) > 1 else axes[0]
-    return shard_map(f, mesh=mesh, in_specs=(P(spec, None), P(spec)), out_specs=P(None, None),
-                     check_vma=False)(edge_feat, dst)
+    return jax.shard_map(f, mesh=mesh, in_specs=(P(spec, None), P(spec)), out_specs=P(None, None),
+                         check_vma=False)(edge_feat, dst)
 
 
 def local_segment_to_edges(trip_feat, ji_local, n_edges_local_total: int, mesh):
@@ -114,8 +112,8 @@ def local_segment_to_edges(trip_feat, ji_local, n_edges_local_total: int, mesh):
         return jax.ops.segment_sum(t_loc, ji_loc, num_segments=e_loc)
 
     spec = axes if len(axes) > 1 else axes[0]
-    return shard_map(f, mesh=mesh, in_specs=(P(spec, None), P(spec)), out_specs=P(spec, None),
-                     check_vma=False)(trip_feat, ji_local)
+    return jax.shard_map(f, mesh=mesh, in_specs=(P(spec, None), P(spec)), out_specs=P(spec, None),
+                         check_vma=False)(trip_feat, ji_local)
 
 
 # ----------------------------------------------------------------- params

@@ -26,8 +26,6 @@ from repro.configs.base import RecsysConfig
 from repro.models.api import ModelBundle, ShapeSpec, StepDef, adamw_state_pspecs, adamw_state_specs, sds
 from repro.train import optimizer as opt
 
-from repro.utils.compat import shard_map
-
 
 # ------------------------------------------------------------ embedding bag
 
@@ -51,7 +49,7 @@ def embedding_bag(tables, ids, mesh, batch_axes):
             out = jax.lax.psum(out, "model")
         return out
 
-    return shard_map(
+    return jax.shard_map(
         f, mesh=mesh,
         in_specs=(P(None, "model", None), P(bspec, None, None)),
         out_specs=P(bspec, None, None),
@@ -254,7 +252,7 @@ def embedding_seq(tables, ids, mesh, batch_axes, field: int = 0):
             g = jax.lax.psum(g, "model")
         return g
 
-    return shard_map(
+    return jax.shard_map(
         f, mesh=mesh,
         in_specs=(P(None, "model", None), P(bspec, None)),
         out_specs=P(bspec, None, None),

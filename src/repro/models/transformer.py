@@ -26,8 +26,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.utils.compat import shard_map
-
 from repro.configs.base import LMConfig
 from repro.distributed.sharding import logical_to_pspec
 from repro.models import layers as L
@@ -185,7 +183,7 @@ def _moe_block(h, lp, cfg: LMConfig, mesh, batch_axes, *, seq_sharded: bool):
             out = jax.lax.psum(out, "model")
         return out.astype(h_loc.dtype), aux
 
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         f, mesh=mesh,
         in_specs=(h_spec, P(None, None), w_in_spec, w_in_spec, w_out_spec),
         out_specs=(h_spec, P()),
@@ -453,7 +451,7 @@ def _flash_decode(q, k_cache, v_cache, layer, cache_len, mesh, bspec, seq_axes, 
         return o.transpose(0, 3, 1, 2, 4).reshape(b, 1, n_heads, dh)
 
     cache_spec = P(None, bspec, seq_axes if len(seq_axes) > 1 else seq_axes[0], None, None)
-    return shard_map(
+    return jax.shard_map(
         f, mesh=mesh,
         in_specs=(P(bspec, None, None, None), cache_spec, cache_spec),
         out_specs=P(bspec, None, None, None),
@@ -479,7 +477,7 @@ def _cache_insert(cache, new, layer, pos, mesh, bspec, seq_axes):
         return jax.lax.dynamic_update_slice(c_l, row[None], (layer, 0, li, 0, 0))
 
     cache_spec = P(None, bspec, seq_axes if len(seq_axes) > 1 else seq_axes[0], None, None)
-    return shard_map(
+    return jax.shard_map(
         f, mesh=mesh,
         in_specs=(cache_spec, P(bspec, None, None, None)),
         out_specs=cache_spec,

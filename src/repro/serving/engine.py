@@ -20,7 +20,7 @@ serve_step:
      serving/scan.py (cfg.impl: auto | ref | pallas | interpret). "ref" is the
      portable jnp path under lax.map; "pallas" runs the fused kernels
      grid-batched over the whole [b_loc, q_cap] dispatch buffer in one launch
-     (kernels.l2_topk_batched for f32; native on TPU, interpreted elsewhere).
+     (kernels.l2_topk_qbuf for f32; Mosaic on TPU, "interpret" for the CPU).
      WHAT is scanned is declared by the serving tier (serving/tiers.py): the
      engine resolves cfg.tier from the registry and iterates the tier's store
      field + scan operand declarations — it never branches on tier-specific
@@ -49,7 +49,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import LiraSystemConfig, ShapeSpec
 from repro.core import probing
@@ -62,7 +62,6 @@ from repro.obs import trace as obs_trace
 from repro.serving import api
 from repro.serving import scan
 from repro.serving import tiers
-from repro.utils.compat import shard_map
 
 
 def batch_mesh_info(mesh):
@@ -155,9 +154,10 @@ def make_serve_step(cfg: LiraSystemConfig, mesh, n_queries: int, *, sigma: float
         # --profile-dir recipe in README "Observability"); it is a pure
         # metadata annotation with zero effect on the computation
         with jax.named_scope("lira.probing"):
+            # HIGHEST: residual_pq derives its exact-distance offsets from cd
             cd = (
                 jnp.sum(q_loc * q_loc, -1, keepdims=True)
-                - 2.0 * q_loc @ cents.T
+                - 2.0 * jnp.dot(q_loc, cents.T, precision=jax.lax.Precision.HIGHEST)
                 + jnp.sum(cents * cents, -1)[None, :]
             )
             p = jax.nn.sigmoid(probing.apply(params, q_loc, cd))    # [q_row, B]
@@ -216,9 +216,9 @@ def make_serve_step(cfg: LiraSystemConfig, mesh, n_queries: int, *, sigma: float
             # replica-aware local merge: redundancy (η>0) stores the same id in
             # several partitions, so a plain top-k would return duplicate ids
             # and corrupt recall@k — dedup to best-distance-per-id instead
-            # (backend dispatch: bitonic Pallas kernel on TPU, jnp elsewhere)
+            # (the same backend as the scan: Pallas kernel or jnp reference)
             loc_d, loc_i = kops.dedup_topk(
-                out_d[:q_row].reshape(q_row, -1), pool_i, k)
+                out_d[:q_row].reshape(q_row, -1), pool_i, k, impl=scan_impl)
 
             # ---- cross-shard merge (O(Q·k·shards) bytes — independent of N);
             # replicas of one id can live on different shards, so dedup again
@@ -230,7 +230,7 @@ def make_serve_step(cfg: LiraSystemConfig, mesh, n_queries: int, *, sigma: float
                     # identical on every model shard → count it exactly once
                     dedup_hits = (jax.lax.psum(dedup_hits, "model")
                                   + _dup_count(all_i))
-                loc_d, loc_i = kops.dedup_topk(all_d, all_i, k)
+                loc_d, loc_i = kops.dedup_topk(all_d, all_i, k, impl=scan_impl)
                 overflow = jax.lax.psum(overflow, "model")
         nprobe_eff = probe_ok.sum(-1).astype(jnp.float32)
         if count_dedup:
@@ -258,7 +258,7 @@ def make_serve_step(cfg: LiraSystemConfig, mesh, n_queries: int, *, sigma: float
             occ = store["ids"] >= 0
         args = (queries, valid, params, store["centroids"], store["vectors"],
                 store["ids"], occ, *(store[n] for n in extra_fields))
-        return shard_map(
+        return jax.shard_map(
             f, mesh=mesh,
             in_specs=in_specs,
             out_specs=out_specs,
@@ -459,7 +459,11 @@ class LiraEngine:
         ids = np.arange(len(x), dtype=np.int32)
         plan = plan_redundancy(params, x, assign, cents, eta=config.eta)
         extra = replica_rows(plan, x, ids)
-        store_h = build_store(x, ids, assign, cents, extra=extra)
+        # whole lane tiles of slots, so the scan kernels stream the store in
+        # place (an unaligned capacity pads a copy of it on every call: 6 GB
+        # at SIFT1M scale); growth and compaction keep the alignment
+        store_h = build_store(x, ids, assign, cents, extra=extra,
+                              align=kops.SLOT_ALIGN)
         dim = x.shape[1]
         cfg = LiraSystemConfig(
             arch="lira", dim=dim, n_partitions=n_partitions,
@@ -477,7 +481,27 @@ class LiraEngine:
         if not cfg.pq_m:  # tiers without PQ leave the knob at its default
             cfg = dataclasses.replace(cfg, pq_m=16)
         return cls(cfg=cfg, params=params, store=store, mesh=mesh,
-                   sigma=config.sigma)
+                   sigma=config.sigma).place()
+
+    def place(self) -> "LiraEngine":
+        """Put the store on the mesh once, each field per its tier's
+        PartitionSpec (partition planes split on "model", the rest
+        replicated), and the probing params replicated — so a serve call on
+        a multi-device mesh never reshards the store. Returns self."""
+        pspecs = tiers.resolve(self.cfg.tier).store_pspecs(self.cfg)
+
+        def put(a, spec):
+            return jax.device_put(a, NamedSharding(self.mesh, spec))
+
+        self.store = {n: put(a, pspecs.get(n, P())) for n, a in self.store.items()}
+        self.params = jax.tree.map(lambda a: put(a, P()), self.params)
+        return self
+
+    def _slot_align(self) -> int:
+        """What a new capacity is rounded up to: whole lane tiles while the
+        store holds them (the build aligns it, so the scan kernels stream the
+        store in place), else 1 — a hand-built store keeps exact sizes."""
+        return kops.SLOT_ALIGN if self.cfg.capacity % kops.SLOT_ALIGN == 0 else 1
 
     def _batch_bucket(self, nq: int) -> int:
         """Pad batch sizes to power-of-two buckets (≥8, rounded up to a
@@ -828,9 +852,10 @@ class LiraEngine:
                 fail = ~plan.ok
                 demand = occ_w.sum(1) + np.bincount(
                     d2[fail].argmin(1), minlength=self.cfg.n_partitions)
-                new_cap = max(int(demand.max()),
-                              int(np.ceil(self.cfg.capacity
-                                          * self._GROW_SLACK)))
+                new_cap = mutable.align_up(
+                    max(int(demand.max()),
+                        int(np.ceil(self.cfg.capacity * self._GROW_SLACK))),
+                    self._slot_align())
                 planes = mutable.grow_store(
                     {n: self.store[n] for n in tier.slot_fields(self.cfg)},
                     new_cap)
@@ -917,7 +942,7 @@ class LiraEngine:
             occ = np.asarray(self.store["occupancy"])
             packed, new_cap = mutable.compact_store(
                 {n: self.store[n] for n in tier.slot_fields(self.cfg)}, occ,
-                min_capacity=self.cfg.k)
+                min_capacity=self.cfg.k, align=self._slot_align())
             shape_changed = new_cap != self.cfg.capacity
             reclaimed = (self.cfg.capacity - new_cap) * self.cfg.n_partitions
             store = dict(self.store)
@@ -1026,7 +1051,8 @@ class LiraEngine:
                 id_all = np.concatenate([idu, ri], 0)
                 a_all = np.concatenate([assign, ra.astype(np.int64)], 0)
             slots, counts = mutable.layout_rows(a_all, nb)
-            needed = max(int(counts.max(initial=1)), self.cfg.k)
+            needed = mutable.align_up(max(int(counts.max(initial=1)), self.cfg.k),
+                                      self._slot_align())
             # capacity only grows when the new layout demands it — a layout
             # that still fits keeps the shape (and the compiled serve steps)
             shape_changed = needed > cap
@@ -1118,4 +1144,4 @@ class LiraEngine:
                    mesh=mesh, sigma=float(extra.get("sigma", 0.5)),
                    epoch=int(extra.get("epoch", 0)),
                    _stale_inserts=(np.asarray(stale, np.int64)
-                                   if stale is not None else None))
+                                   if stale is not None else None)).place()

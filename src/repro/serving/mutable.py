@@ -97,13 +97,19 @@ def pack_order(occ: np.ndarray):
     return perm, occ.sum(1).astype(np.int64)
 
 
+def align_up(n: int, align: int) -> int:
+    """``n`` rounded up to a multiple of ``align``."""
+    return -(-int(n) // align) * align
+
+
 def compact_store(planes: dict, occ: np.ndarray, *,
-                  min_capacity: int = 1) -> tuple[dict, int]:
+                  min_capacity: int = 1, align: int = 1) -> tuple[dict, int]:
     """Repack live slots to the front of each partition and shrink capacity
-    to the max live count: tombstones and free holes are squeezed out, dead
-    tail slots reset to their pad sentinels. Returns (planes, new_cap)."""
+    to the max live count (rounded up to a multiple of ``align``): tombstones
+    and free holes are squeezed out, dead tail slots reset to their pad
+    sentinels. Returns (planes, new_cap)."""
     perm, live = pack_order(occ)
-    new_cap = max(int(min_capacity), int(live.max(initial=0)))
+    new_cap = align_up(max(int(min_capacity), int(live.max(initial=0))), align)
     rows = np.arange(occ.shape[0])[:, None]
     dead = np.arange(new_cap)[None, :] >= live[:, None]     # [B, new_cap]
     out = {}

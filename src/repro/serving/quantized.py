@@ -8,8 +8,8 @@ compressed-scan-then-rerank split):
   stage 0 (per query, once):  ADC LUT  [m, ks] subspace distance table;
   stage 1 (per probed partition): LUT scan over the partition's codes →
           shortlist of ``r·k`` candidate slots. The scan is backend-dispatched
-          through ``serving/scan.py``: ``kernels.pq_adc_topk_batched`` fuses
-          it over every dispatch bucket in one launch (native on TPU,
+          through ``serving/scan.py``: ``kernels.pq_adc_topk_qbuf`` fuses
+          it over every dispatch bucket in one launch (Mosaic on TPU,
           interpretable anywhere); the jnp gather path is the portable
           reference and parity oracle;
   stage 2: exact f32 distances on the shortlist only → top-k, then the usual

@@ -20,12 +20,14 @@ interchangeable implementations:
                     one copy per occupied dispatch slot, so stage-1 staging is
                     O(q_row·row) + O(b_loc·q_cap) indices instead of
                     O(b_loc·q_cap·row) (see ``staged_operand_bytes``).
-                    Compiles natively on TPU, interprets elsewhere;
-  * ``interpret`` — the kernels forced through the Pallas interpreter on any
+                    Compiles with Mosaic and so needs a TPU;
+  * ``interpret`` — the same kernels through the Pallas interpreter, on any
                     backend (what CI's parity suite and bench smoke run).
 
-Tier semantics (identical across impls — the parity suite asserts bit-equal
-distances and set-equal ids):
+Tier semantics (identical across impls — on the CPU the parity suite asserts
+bit-equal distances and set-equal ids; on a TPU the kernel's MXU and XLA's
+dot may round the last bit differently). Every f32 distance is taken at
+HIGHEST precision: a default f32 dot on the TPU rounds its operands to bf16.
 
   f32:        fused L2 + running top-k over the partition's vectors;
   quantized:  stage 1 ADC shortlist of ``rk`` slots from the shared per-query
@@ -40,6 +42,7 @@ import jax.numpy as jnp
 from repro.kernels import ops as kops
 
 IMPLS = ("ref", "pallas", "interpret")
+_EXACT = jax.lax.Precision.HIGHEST
 
 
 def resolve_impl(impl: str | None) -> str:
@@ -89,14 +92,17 @@ def run(impl: str | None, qbuf, q_pad, vecs_loc, ids_loc, k: int, *,
 def _f32_ref(qbuf, q_pad, vecs_loc, ids_loc, k):
     def scan_partition(args):
         qi, vec_b, id_b = args                               # [q_cap], [cap, d], [cap]
-        qs = q_pad[qi].astype(vec_b.dtype)                   # [q_cap, d]
-        # bf16 operands + f32 accumulation (store_dtype=bfloat16 halves the
-        # dominant vector-read traffic; exact rerank happens at f32)
+        # the query is quantized to the store dtype (store_dtype=bfloat16
+        # halves the dominant vector-read traffic), then both operands are
+        # upcast to f32 before the dot — the same point as the kernel
+        qs = q_pad[qi].astype(vec_b.dtype).astype(jnp.float32)  # [q_cap, d]
+        vec = vec_b.astype(jnp.float32)
         d2 = (
-            jnp.sum(qs.astype(jnp.float32) ** 2, -1, keepdims=True)
-            - 2.0 * jax.lax.dot_general(qs, vec_b, (((1,), (1,)), ((), ())),
-                                        preferred_element_type=jnp.float32)
-            + jnp.sum(vec_b.astype(jnp.float32) ** 2, -1)[None, :]
+            jnp.sum(qs ** 2, -1, keepdims=True)
+            - 2.0 * jax.lax.dot_general(qs, vec, (((1,), (1,)), ((), ())),
+                                        preferred_element_type=jnp.float32,
+                                        precision=_EXACT)
+            + jnp.sum(vec ** 2, -1)[None, :]
         )
         d2 = jnp.where(id_b[None, :] < 0, jnp.inf, d2)
         neg, posk = jax.lax.top_k(-d2, k)
@@ -141,7 +147,7 @@ def _quantized_ref(qbuf, q_pad, vecs_loc, ids_loc, k, lut_pad, codes_loc, rk,
         cid = id_b[sl]
         d2 = (
             jnp.sum(qs * qs, -1)[:, None]
-            - 2.0 * jnp.einsum("qd,qrd->qr", qs, cand)
+            - 2.0 * jnp.einsum("qd,qrd->qr", qs, cand, precision=_EXACT)
             + jnp.sum(cand * cand, -1)
         )
         d2 = jnp.where(cid < 0, jnp.inf, d2)
@@ -181,7 +187,7 @@ def _quantized_kernel(qbuf, q_pad, vecs_loc, ids_loc, k, lut_pad, codes_loc, rk,
     qs = q_pad[qbuf].astype(jnp.float32)                     # [b_loc, q_cap, d]
     d2 = (
         jnp.sum(qs * qs, -1)[..., None]
-        - 2.0 * jnp.einsum("bqd,bqrd->bqr", qs, cand)
+        - 2.0 * jnp.einsum("bqd,bqrd->bqr", qs, cand, precision=_EXACT)
         + jnp.sum(cand * cand, -1)
     )
     d2 = jnp.where(cid < 0, jnp.inf, d2)

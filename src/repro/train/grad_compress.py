@@ -17,7 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.utils.compat import shard_map
 
 
 def _quantize(x: jax.Array):
@@ -39,7 +38,7 @@ def compressed_psum_pod(grads, err, mesh):
             tot = jax.lax.psum(deq, "pod") / npod
             return tot, new_e
 
-        return shard_map(
+        return jax.shard_map(
             f, mesh=mesh,
             in_specs=(P(), P()), out_specs=(P(), P()),
             check_vma=False,

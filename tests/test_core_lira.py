@@ -199,3 +199,21 @@ def test_bliss_groups_route(small_dataset):
     res = ret.merge_groups(ptks, masks, gti3, k, [g.assign for g in groups], 3000)
     assert res.recall > 0.3  # routing is learned, not random
     assert res.cmp_mean <= 3000
+
+
+def test_exact_knn_exclude_self_matches_row_loop():
+    """The vectorized self-match filter keeps, per row, the first k columns
+    at distance > 1e-9 (columns 1..k when duplicates leave fewer) — the row
+    loop it replaced, kept here as the reference."""
+    x = np.random.default_rng(3).normal(size=(300, 8)).astype(np.float32)
+    x[5] = x[6]                  # one duplicate pair
+    x[7] = x[8] = x[9] = x[10]   # a duplicate group
+    k = 6
+    d, i = gt.exact_knn(x, x, k, exclude_self=True)
+    full_d, full_i = gt.exact_knn(x, x, k + 1)
+    for r in range(len(x)):
+        cols = [c for c in range(k + 1) if full_d[r, c] > 1e-9][:k]
+        if len(cols) < k:
+            cols = list(range(1, k + 1))
+        np.testing.assert_array_equal(d[r], full_d[r, cols])
+        np.testing.assert_array_equal(i[r], full_i[r, cols])

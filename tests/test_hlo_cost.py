@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 
 from repro.launch import hlo_cost
-from repro.utils.compat import make_mesh, shard_map
+from repro.launch.mesh import make_mesh
 
 
 def _compile(fn, *specs, in_shardings=None):
@@ -60,7 +60,7 @@ def test_collective_bytes_counted():
     mesh = make_mesh((1, 1), ("data", "model"))
 
     def f(x):
-        return shard_map(lambda a: jax.lax.psum(a, "model"), mesh=mesh,
+        return jax.shard_map(lambda a: jax.lax.psum(a, "model"), mesh=mesh,
                              in_specs=jax.sharding.PartitionSpec(None, None),
                              out_specs=jax.sharding.PartitionSpec(None, None),
                              check_vma=False)(x)

@@ -261,6 +261,37 @@ def _build(x, tier, **kw):
     return LiraEngine.build(make_test_mesh(), x, BuildConfig(**cfg))
 
 
+def test_built_capacity_stays_lane_aligned_through_mutations():
+    """The build keeps capacity whole 128-lane tiles, so the scan kernels
+    stream the store in place; compaction, growth and repartition keep it
+    there (an unaligned capacity pads a copy of the store every call)."""
+    from repro.kernels import ops as kops
+
+    ds = make_vector_dataset(n=800, n_queries=4, dim=16, n_modes=8, seed=43)
+    eng = _build(ds.base, "f32", epochs=1, train_frac=0.5)
+    align = kops.SLOT_ALIGN
+    live = np.asarray(eng.store["occupancy"]).sum(1)
+    assert eng.cfg.capacity % align == 0 and eng.cfg.capacity - align < live.max()
+    eng.delete(np.arange(0, len(ds.base), 2))
+    eng.compact()
+    assert eng.cfg.capacity % align == 0
+    cap = eng.cfg.capacity
+    n_new = cap * eng.cfg.n_partitions + 1         # more rows than the store holds
+    c0 = np.asarray(eng.store["centroids"])[0]
+    x_new = c0 + np.random.default_rng(0).normal(0, 0.01, (n_new, 16)).astype(np.float32)
+    eng.insert(x_new, np.arange(n_new) + 10_000)
+    assert eng.cfg.capacity > cap and eng.cfg.capacity % align == 0
+    eng.maybe_repartition(force=True)
+    assert eng.cfg.capacity % align == 0
+
+
+def test_compact_store_rounds_capacity_up_to_align():
+    occ = np.array([[True, False, True], [True, False, False]])
+    _, cap = mutable.compact_store({"occupancy": occ}, occ, align=8)
+    assert cap == 8
+    assert mutable.align_up(17, 8) == 24 and mutable.align_up(16, 8) == 16
+
+
 @pytest.mark.parametrize("tier", CHURN_TIERS)
 def test_sustained_churn_recall_matches_fresh_rebuild(tier):
     """≥20% of the base churned (deletes + inserts) with periodic

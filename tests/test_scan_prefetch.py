@@ -157,14 +157,16 @@ def test_autotune_cache_key_path():
     autotune.clear()
     try:
         t1 = autotune.autotune_pq_adc_qbuf(32, 2, 16, 4, candidates=(8, 16),
-                                           b_loc=2, q_cap=4, q_row=6)
+                                           b_loc=2, q_cap=4, q_row=6,
+                                           impl="interpret")
         assert t1 in (8, 16)
         recs = autotune.records()
         assert len(recs) == 1 and recs[0]["cached"] is False
         assert set(recs[0]["timings_s"]) == {"8", "16"}
         # same store shape → cache hit, no re-sweep, recorded as cached
         t2 = autotune.autotune_pq_adc_qbuf(32, 2, 16, 4, candidates=(8, 16),
-                                           b_loc=2, q_cap=4, q_row=6)
+                                           b_loc=2, q_cap=4, q_row=6,
+                                           impl="interpret")
         assert t2 == t1
         recs = autotune.records()
         assert len(recs) == 2 and recs[1]["cached"] is True
@@ -181,7 +183,8 @@ def test_autotune_l2_sweep_records():
     autotune.clear()
     try:
         t = autotune.autotune_l2_qbuf(32, 8, 4, candidates=(8, 16),
-                                      b_loc=2, q_cap=4, q_row=6)
+                                      b_loc=2, q_cap=4, q_row=6,
+                                      impl="interpret")
         assert t in (8, 16)
         assert autotune.lookup(autotune.l2_key(32, 8, 4)) == t
     finally:

@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.utils.compat import make_mesh
+from repro.launch.mesh import make_mesh
 
 from repro.ckpt import CheckpointManager
 from repro.data.pipeline import PipelineSpec, TokenPipeline
