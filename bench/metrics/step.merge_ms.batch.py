@@ -1,0 +1,7 @@
+"""step.merge_ms.batch (ms/step): device time under ``lira.merge`` per serve
+step, from the trace."""
+from lirabench.series import scope_ms_per_step
+
+
+def read(run):
+    return scope_ms_per_step(run, "lira.merge")
