@@ -1,9 +1,10 @@
 """Observability: metrics registry, span tracing, and profiler capture.
 
-The measurement half of the perf campaign (ROADMAP item 4): every serving
-stage is spanned, every query-aware distribution (nprobe_eff, overflow,
-replica-dedup, batch shape) is a registry metric, and kernel suites persist
-roofline-relative BENCH_*.json snapshots. See README "Observability".
+Every serving stage is spanned, and every query-aware distribution
+(nprobe_eff, overflow, replica-dedup, batch shape, queue and head-of-line
+wait) is a registry metric. An enabled ``Tracer`` also writes its spans into
+``jax.profiler`` captures, on the same clock as the device operations. See
+README "Observability".
 """
 from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                                default_registry, parse_exposition)

@@ -5,9 +5,10 @@ when ``profile_dir`` is truthy and is a transparent no-op otherwise — so the
 launchers and benchmark runner can take ``--profile-dir`` unconditionally.
 The capture lands in ``<profile_dir>/plugins/profile/<ts>/`` ready for
 TensorBoard's profile plugin; the serve step's ``jax.named_scope`` blocks
-(probing / dispatch / scan / merge) make the op_profile tab read in LIRA's
-stage vocabulary instead of raw HLO op names. See README "Observability" for
-the capture → TensorBoard recipe.
+(probing / dispatch / scan / merge, and telemetry for the dedup counter)
+make the op_profile tab read in LIRA's stage vocabulary instead of raw HLO op
+names, and an enabled ``Tracer``'s spans land on the host plane beside them.
+See README "Observability" for the capture → TensorBoard recipe.
 """
 from __future__ import annotations
 

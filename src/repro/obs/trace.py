@@ -22,10 +22,16 @@ Three design constraints from the serving stack:
     tracing only reads clocks around stages.
   * **Bounded memory** — finished spans land in a ring (``max_spans``); a
     ``sink`` (path or callable) can stream them out as JSON-lines instead.
+  * **On the profiler clock** — an enabled tracer also opens a
+    ``jax.profiler.TraceAnnotation`` of the span's name around each span, so
+    a ``jax.profiler`` capture holds the spans on its host plane, on the
+    same clock as the device operations. Attrs stay in the ``Span`` only.
+    ``jax.profiler`` is imported on the first span, not with this module.
 """
 from __future__ import annotations
 
 import contextlib
+import importlib
 import io
 import itertools
 import json
@@ -88,21 +94,27 @@ class Tracer:
         self._finished: list[Span] = []
         self._max_spans = int(max_spans)
         self._ids = itertools.count(1)
+        self._profiler = None
 
     @contextlib.contextmanager
     def span(self, name: str, **attrs):
-        parent = self._stack[-1] if self._stack else None
-        sp = Span(name, next(self._ids),
-                  parent.span_id if parent else None, self._clock())
-        if attrs:
-            sp.attrs.update(attrs)
-        self._stack.append(sp)
-        try:
-            yield sp
-        finally:
-            sp.t_end = self._clock()
-            self._stack.pop()
-            self._record(sp)
+        if self._profiler is None:
+            self._profiler = importlib.import_module("jax.profiler")
+        # the annotation encloses the span's clock reads, so a span's
+        # duration leaves out its own annotation's cost
+        with self._profiler.TraceAnnotation(name):
+            parent = self._stack[-1] if self._stack else None
+            sp = Span(name, next(self._ids),
+                      parent.span_id if parent else None, self._clock())
+            if attrs:
+                sp.attrs.update(attrs)
+            self._stack.append(sp)
+            try:
+                yield sp
+            finally:
+                sp.t_end = self._clock()
+                self._stack.pop()
+                self._record(sp)
 
     def _record(self, sp: Span) -> None:
         self._finished.append(sp)

@@ -208,11 +208,17 @@ def make_serve_step(cfg: LiraSystemConfig, mesh, n_queries: int, *, sigma: float
             out_d = out_d.at[qbuf, cols].set(dists, mode="drop")
             out_i = out_i.at[qbuf, cols].set(rids, mode="drop")
             pool_i = out_i[:q_row].reshape(q_row, -1)
-            # replica-dedup hit rate (only when asked for: the extra output
-            # changes the step signature, so make_bundle and direct callers
-            # keep the 4-output form) — measured BEFORE each dedup pass so it
-            # counts exactly the duplicate slots the merges collapse
-            dedup_hits = _dup_count(pool_i) if count_dedup else None
+        # replica-dedup hit rate (only when asked for: the extra output
+        # changes the step signature, so make_bundle and direct callers keep
+        # the 4-output form) — measured BEFORE each dedup pass so it counts
+        # exactly the duplicate slots the merges collapse. Its own scope
+        # beside lira.merge, never inside it, so a trace books the counter's
+        # device time apart from the merge's
+        dedup_hits = None
+        if count_dedup:
+            with jax.named_scope("lira.telemetry"):
+                dedup_hits = _dup_count(pool_i)
+        with jax.named_scope("lira.merge"):
             # replica-aware local merge: redundancy (η>0) stores the same id in
             # several partitions, so a plain top-k would return duplicate ids
             # and corrupt recall@k — dedup to best-distance-per-id instead
@@ -220,16 +226,19 @@ def make_serve_step(cfg: LiraSystemConfig, mesh, n_queries: int, *, sigma: float
             loc_d, loc_i = kops.dedup_topk(
                 out_d[:q_row].reshape(q_row, -1), pool_i, k, impl=scan_impl)
 
-            # ---- cross-shard merge (O(Q·k·shards) bytes — independent of N);
-            # replicas of one id can live on different shards, so dedup again
-            if model_n > 1:
+        # ---- cross-shard merge (O(Q·k·shards) bytes — independent of N);
+        # replicas of one id can live on different shards, so dedup again
+        if model_n > 1:
+            with jax.named_scope("lira.merge"):
                 all_d = jax.lax.all_gather(loc_d, "model", axis=1, tiled=True)   # [q_row, 16k]
                 all_i = jax.lax.all_gather(loc_i, "model", axis=1, tiled=True)
-                if count_dedup:
-                    # local hits differ per shard → psum; the gathered pool is
-                    # identical on every model shard → count it exactly once
+            if count_dedup:
+                with jax.named_scope("lira.telemetry"):
+                    # local hits differ per shard → psum; the gathered pool
+                    # is identical on every model shard → count it once
                     dedup_hits = (jax.lax.psum(dedup_hits, "model")
                                   + _dup_count(all_i))
+            with jax.named_scope("lira.merge"):
                 loc_d, loc_i = kops.dedup_topk(all_d, all_i, k, impl=scan_impl)
                 overflow = jax.lax.psum(overflow, "model")
         nprobe_eff = probe_ok.sum(-1).astype(jnp.float32)
@@ -613,10 +622,13 @@ class LiraEngine:
                 valid[:nq] = True
             with tr.span("engine.device", tier=tier_obj.name, impl=impl,
                          bucket=nq_pad, cache_hit=cache_hit) as sp_dev:
-                with self.mesh:
+                # dispatch returns once the step is enqueued; wait is the
+                # device running it (split so a stall names its side)
+                with tr.span("engine.dispatch"), self.mesh:
                     out = fn(self.params, self.store, jnp.asarray(qp),
                              jnp.asarray(valid))
-                d, i, npb, ovf, dups = jax.block_until_ready(out)
+                with tr.span("engine.wait"):
+                    d, i, npb, ovf, dups = jax.block_until_ready(out)
             with tr.span("engine.post") as sp_post:
                 npb_np = np.asarray(npb)[:nq]
                 overflow = int(np.asarray(ovf).sum())

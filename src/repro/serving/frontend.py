@@ -19,7 +19,9 @@ tail latency). ``ServingFrontend`` is that layer:
     (or the newcomer, if nothing queued outranks it) resolves immediately
     with an empty answer marked ``SearchStats.shed=True``, keeping tail
     latency bounded for the traffic that is admitted;
-  * **latency telemetry** — every served request records its queue wait and
+  * **latency telemetry** — every served request records its queue wait, the
+    head-of-line part of it (``lira_frontend_hol_ms``: the wait that
+    overlapped a batch this front-end was already serving) and its
     end-to-end latency against the injected clock, into log-spaced histograms
     in a metrics registry (repro.obs.metrics) labeled ``frontend=<name>`` —
     O(buckets) memory for a long-lived process, unlike the per-observation
@@ -47,6 +49,7 @@ group early rather than deadlocking).
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import itertools
 import time
@@ -132,6 +135,10 @@ class ServingFrontend:
     "Observability" for the span hierarchy.
     """
 
+    # served batches kept for head-of-line accounting: a request that waited
+    # through more of them than this reads a lower bound
+    _BUSY_KEEP = 64
+
     def __init__(self, engine, config: FrontendConfig | None = None, *,
                  clock: Callable[[], float] = time.monotonic,
                  charge_service: bool = False,
@@ -156,6 +163,9 @@ class ServingFrontend:
         self._seq = 0
         self._t_first: Optional[float] = None
         self._t_last_done: Optional[float] = None
+        # [t_launch, t_done] of the latest batches served, oldest first: a
+        # queued request's head-of-line wait is its wait's overlap with them
+        self._busy: collections.deque = collections.deque(maxlen=self._BUSY_KEEP)
 
     def _tr(self):
         return self.tracer if self.tracer is not None else self.engine._tracer()
@@ -196,6 +206,12 @@ class ServingFrontend:
     def _h_queue(self):
         return self._m().histogram("lira_frontend_queue_ms",
                                    "enqueue → batch-launch wait")
+
+    def _h_hol(self):
+        return self._m().histogram("lira_frontend_hol_ms",
+                                   "the part of the enqueue → batch-launch "
+                                   "wait spent behind batches this "
+                                   "front-end was already serving")
 
     def _h_batch_rows(self):
         return self._m().histogram(
@@ -365,6 +381,18 @@ class ServingFrontend:
             n_calls += 1
         return n_calls
 
+    def _hol_ms(self, t_enq: float, t_launch: float) -> float:
+        """Milliseconds of [t_enq, t_launch] during which an earlier batch of
+        this front-end was being served (the engine call is synchronous, so
+        the batches' intervals never overlap, and the batch launched at
+        ``t_launch`` adds nothing)."""
+        hol = 0.0
+        for t0, t1 in reversed(self._busy):
+            if t1 <= t_enq:
+                break
+            hol += max(0.0, min(t1, t_launch) - max(t0, t_enq))
+        return hol * 1e3
+
     def _serve_batch(self, key: tuple, batch: list[PendingSearch]) -> None:
         k, sigma, tier, impl = key
         tr = self._tr()
@@ -382,6 +410,7 @@ class ServingFrontend:
             if self.charge_service:
                 self.clock.advance(self.service_timer() - t0)
             t_done = self.clock()
+            self._busy.append((t_launch, t_done))
             with tr.span("frontend.scatter") as sp_scat:
                 row = 0
                 for pending in batch:
@@ -411,6 +440,8 @@ class ServingFrontend:
                             epoch=res.stats.epoch))
                     self._c_served().inc(**self._lbl)
                     self._h_queue().observe(queue_ms, **self._lbl)
+                    self._h_hol().observe(
+                        self._hol_ms(pending.t_enq, t_launch), **self._lbl)
                     self._h_latency().observe(latency_ms, **self._lbl)
             sp_batch.set(rows=len(queries))
         self._c_batches().inc(**self._lbl)

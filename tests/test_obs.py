@@ -8,12 +8,17 @@ bucket math, span nesting on FakeClock, and the serving integration gates:
     shared virtual clock;
   * the regression that keeps tracing safe to leave on: tracing-on results
     are bit-identical to tracing-off across {f32, pq, residual_pq} ×
-    {ref, interpret}.
+    {ref, interpret};
+  * an enabled tracer's spans reach the profiler (one annotation per span,
+    none from NOOP), ``engine.device`` splits into dispatch and wait, the
+    front-end's head-of-line wait is exact on a virtual clock, and the
+    dedup counter compiles under its own ``lira.telemetry`` scope.
 
 All wall-clock-free: tracers run on FakeClock (or are compared only for
 structure), so nothing here can flake on a loaded CI box.
 """
 import dataclasses
+import itertools
 import json
 
 import jax
@@ -248,6 +253,52 @@ def test_noop_tracer_is_inert():
     assert NOOP.finished() == []
 
 
+class _AnnotationRecorder:
+    """Stands in for ``jax.profiler.TraceAnnotation``: logs each open/close."""
+
+    log: list = []
+
+    def __init__(self, name, **kwargs):
+        self.name = name
+        self.kwargs = kwargs
+
+    def __enter__(self):
+        self.log.append(("open", self.name))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("close", self.name))
+        return False
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    log = []
+    monkeypatch.setattr(_AnnotationRecorder, "log", log)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _AnnotationRecorder)
+    return log
+
+
+def test_enabled_tracer_opens_one_profiler_annotation_per_span(annotations):
+    clock = FakeClock()
+    tr = Tracer(clock=clock)
+    with tr.span("outer", tier="f32"):
+        clock.advance(1e-3)
+        with tr.span("inner"):
+            clock.advance(2e-3)
+    assert annotations == [("open", "outer"), ("open", "inner"),
+                           ("close", "inner"), ("close", "outer")]
+    # the FakeClock timing of the spans is unchanged
+    assert [round(s.duration_ms, 9) for s in tr.finished()] == [2.0, 3.0]
+
+
+def test_noop_tracer_opens_no_profiler_annotation(annotations):
+    with NOOP.span("anything", tier="f32"):
+        with NOOP.span("nested"):
+            pass
+    assert annotations == []
+
+
 # --------------------------------------------------- serving integration
 
 
@@ -344,6 +395,58 @@ def test_engine_metrics_and_stage_sum(obs_engines):
         assert r.stats.latency_ms > 0
         assert sum(r.stats.stages.values()) <= r.stats.latency_ms
         assert sum(r.stats.stages.values()) >= 0.5 * r.stats.latency_ms
+
+
+def test_engine_device_splits_into_dispatch_and_wait(obs_engines):
+    engines, q = obs_engines
+    eng = engines["f32"]
+    tr = Tracer()
+    eng.tracer = tr
+    try:
+        res = eng.search(SearchRequest(queries=q))
+    finally:
+        eng.tracer = None
+    (root,) = tr.finished("engine.search")
+    assert [s.name for s in tr.children(root)] == [
+        "engine.prepare", "engine.device", "engine.post"]
+    (dev,) = tr.finished("engine.device")
+    assert [s.name for s in tr.children(dev)] == ["engine.dispatch",
+                                                  "engine.wait"]
+    assert set(res.stats.stages) == {"prepare", "device", "post"}
+
+
+def _scoped_op_names(count_dedup: bool) -> list:
+    """``op_name`` of every instruction of a tiny compiled serve step."""
+    import re
+
+    from repro.serving.engine import make_serve_step
+
+    b, cap, dim = 4, 48, 16
+    cfg = LiraSystemConfig(arch="t", dim=dim, n_partitions=b, capacity=cap,
+                           k=5, nprobe_max=b)
+    params = probing.init(jax.random.PRNGKey(0),
+                          probing.ProbingConfig(dim=dim, n_partitions=b))
+    store = {"centroids": jnp.zeros((b, dim)),
+             "vectors": jnp.zeros((b, cap, dim)),
+             "ids": jnp.zeros((b, cap), jnp.int32)}
+    step = make_serve_step(cfg, make_test_mesh(), 8, count_dedup=count_dedup)
+    text = jax.jit(step).lower(params, store, jnp.zeros((8, dim)),
+                               jnp.ones((8,), bool)).compile().as_text()
+    return re.findall(r'op_name="([^"]*)"', text)
+
+
+def test_dedup_counter_runs_under_its_own_scope_beside_the_merge():
+    """``_dup_count`` is telemetry, not merge work: its ops carry
+    ``lira.telemetry`` and never ``lira.merge``, so a trace books the
+    counter's device time apart from ``step.merge_ms``."""
+    names = _scoped_op_names(count_dedup=True)
+    tele = [n for n in names if "lira.telemetry" in n]
+    assert any(n.endswith("/sort") for n in tele), tele
+    assert not [n for n in tele if "lira.merge" in n]
+    assert any("lira.merge" in n for n in names)
+    # without the counter the scope is gone: it holds nothing else
+    assert not [n for n in _scoped_op_names(count_dedup=False)
+                if "lira.telemetry" in n]
 
 
 def test_overflow_rate_counts_dropped_probes_once(obs_engines):
@@ -497,3 +600,78 @@ def test_shed_reasons_are_labeled(obs_engines):
     assert c.value(frontend=fe.name, reason="rejected") == 1
     assert fe.stats().shed == 3
     fe.drain()
+
+
+def _record_observations(reg, name):
+    """Every value observed into histogram ``name``, in order."""
+    h = reg.histogram(name)
+    seen = []
+    orig = h.observe
+
+    def observe(value, **labels):
+        seen.append(value)
+        orig(value, **labels)
+
+    h.observe = observe
+    return seen
+
+
+def _slow_frontend(eng, service_s, **cfg_kw):
+    """A front-end on a virtual clock whose every engine call takes
+    ``service_s`` seconds of it."""
+    clock = FakeClock()
+    ticks = itertools.count(0.0, service_s)
+    reg = MetricsRegistry()
+    cfg = dict(max_batch=8, max_wait_ms=2.0, max_queue=64)
+    cfg.update(cfg_kw)
+    fe = ServingFrontend(eng, FrontendConfig(**cfg), clock=clock,
+                         charge_service=True,
+                         service_timer=lambda: next(ticks),
+                         metrics=reg)
+    return fe, clock, reg
+
+
+def test_frontend_hol_is_the_wait_behind_a_batch_in_flight(obs_engines):
+    """A request enqueued while a batch is in flight waits, head-of-line,
+    for that batch's remaining time; one enqueued on an idle front-end
+    waits for nothing but coalescing."""
+    engines, q = obs_engines
+    fe, clock, reg = _slow_frontend(engines["f32"], service_s=5.0)
+    hol = _record_observations(reg, "lira_frontend_hol_ms")
+    queue = _record_observations(reg, "lira_frontend_queue_ms")
+    fe.submit(SearchRequest(queries=q[0]))          # t=0, idle front-end
+    clock.advance(2.5e-3)
+    fe.poll()                                       # batch A: [2.5 ms, 5.0025 s]
+    t_done_a = clock()
+    assert t_done_a == pytest.approx(5.0025)
+    # B was due 2 s into A's batch: submitted late, stamped with its arrival
+    fe.submit(SearchRequest(queries=q[1]), t_arrival=2.0)
+    clock.advance(1e-3)
+    fe.poll()
+    assert hol == [pytest.approx(0.0), pytest.approx((t_done_a - 2.0) * 1e3)]
+    assert queue[1] == pytest.approx((t_done_a + 1e-3 - 2.0) * 1e3)
+    assert queue[0] == pytest.approx(2.5)
+    assert all(h <= w for h, w in zip(hol, queue))
+    h = reg.histogram("lira_frontend_hol_ms")
+    assert h.count(frontend=fe.name) == 2
+
+
+def test_frontend_hol_counts_earlier_batches_of_its_own_flush(obs_engines):
+    """A group beyond ``max_batch`` rows flushes as several engine calls in a
+    row: a later batch's requests wait behind the earlier ones, never longer
+    than their whole queue wait."""
+    engines, q = obs_engines
+    fe, clock, reg = _slow_frontend(engines["f32"], service_s=3.0,
+                                    max_batch=8, max_wait_ms=50.0)
+    hol = _record_observations(reg, "lira_frontend_hol_ms")
+    queue = _record_observations(reg, "lira_frontend_queue_ms")
+    fe.submit(SearchRequest(queries=q[0:3]))         # t=0
+    clock.advance(1e-3)
+    fe.submit(SearchRequest(queries=q[3:6]))
+    fe.submit(SearchRequest(queries=q[6:9]))         # 9 rows > 8: flush
+    assert fe.stats().batches == 2                   # [3+3 rows], then [3]
+    assert hol == [pytest.approx(0.0), pytest.approx(0.0),
+                   pytest.approx(3000.0)]
+    assert queue == [pytest.approx(1.0), pytest.approx(0.0),
+                     pytest.approx(3000.0)]
+    assert all(h <= w for h, w in zip(hol, queue))

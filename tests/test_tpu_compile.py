@@ -12,6 +12,7 @@ process at a time may load the TPU library, and every test worker imports
 this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -147,5 +148,8 @@ def test_model_sharded_serve_step_compiles_on_four_chips(topo):
     text = compiled.as_text()
     assert "all-gather" in text
     assert text.count("tpu_custom_call") >= 3  # scan + local merge + cross-shard merge
+    # the dedup counter, local and cross-shard, sits beside the merge scope
+    tele = re.findall(r'op_name="([^"]*lira\.telemetry[^"]*)"', text)
+    assert tele and not [n for n in tele if "lira.merge" in n]
     # per-device bytes: each chip holds a quarter of the partition planes
     assert _device_bytes(compiled) < _device_bytes(_compile_serve_step(topo.devices, 1))
