@@ -84,6 +84,19 @@ def merge_running(run_v, run_i, blk_v, blk_ids, k: int):
     return top_k_rounds(vals, ids, k, run_v.shape[1])
 
 
+def live_blocks(qbuf, cand_ids, tile: int, empty_row: int):
+    """Candidate blocks a qbuf scan streams per bucket, ``[B]`` int32: none
+    for a bucket whose slots all hold ``empty_row`` (dispatch packs a
+    bucket's queries from slot 0), else the ``tile``-slot blocks up to the
+    last live slot (id ≥ 0) of ``cand_ids [B, C]``. A block past that slot
+    holds only padding, which scores (NEG_BIG, -1) exactly as a running
+    top-k starts, so leaving it out changes no result."""
+    slot = jnp.arange(1, cand_ids.shape[1] + 1, dtype=jnp.int32)
+    extent = jnp.max(jnp.where(cand_ids >= 0, slot, 0), axis=1)
+    occupied = jnp.any(qbuf != empty_row, axis=1)
+    return jnp.where(occupied, -(-extent // tile), 0).astype(jnp.int32)
+
+
 def flush_running(run_v, run_i):
     """Running top-k of -dist² → (ascending dist², ids); slots never filled
     by a valid candidate become (inf, -1), like the jnp oracles."""

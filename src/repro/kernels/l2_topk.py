@@ -26,7 +26,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels._util import (NEG_BIG, flush_running, lane_width,
-                                 merge_running, running_init)
+                                 live_blocks, merge_running, running_init)
 
 
 def neg_sq_l2(q, c, cid):
@@ -166,49 +166,61 @@ def l2_topk_batched(
     return od[..., :k], oi[..., :k]
 
 
-def _l2_topk_qbuf_kernel(qb_ref, q_hbm, vec_hbm, cid_ref, od_ref, oi_ref,
+def _l2_topk_qbuf_kernel(qb_ref, nb_ref, q_hbm, vec_hbm, cid_ref, od_ref, oi_ref,
                          q_s, vbuf, sem_q, sem_vec,
-                         *, k: int, tc: int, n_cblocks: int, n_slots: int):
+                         *, k: int, tc: int, n_slots: int):
     """One bucket per grid step: scalar-prefetched query-row gather (the
     dispatch-buffer rows land in SMEM ahead of the body, so `.at[qb_ref[b,s]]`
-    is a plain dynamic DMA index) followed by double-buffered candidate-block
-    streaming into the running top-k — same merge scheme as the grid-batched
-    kernel, same arithmetic order, so distances stay bit-identical."""
+    is a plain dynamic DMA index) followed by double-buffered streaming of
+    the bucket's first ``nb_ref[b]`` candidate blocks into the running
+    top-k — same merge scheme as the grid-batched kernel, same arithmetic
+    order, so distances stay bit-identical. The blocks left out hold only
+    padding (``_util.live_blocks``); a bucket with none to stream has no
+    query and gathers nothing."""
     b = pl.program_id(0)
+    n_blk = nb_ref[b]
+    width = od_ref.shape[-1]
 
-    # phase 1: gather this bucket's S query rows from the compact plane (rows
-    # on an untiled leading axis: a one-row slice of a tiled axis is not a
-    # legal DMA window on the TPU)
-    def gather(s, carry):
-        cp = pltpu.make_async_copy(q_hbm.at[qb_ref[b, s]], q_s.at[s], sem_q)
-        cp.start()
-        cp.wait()
-        return carry
+    @pl.when(n_blk == 0)
+    def _no_query():
+        od_ref[0], oi_ref[0] = flush_running(*running_init(n_slots, width))
 
-    jax.lax.fori_loop(0, n_slots, gather, 0)
-    q = q_s[...].reshape(n_slots, -1).astype(jnp.float32)   # [S, d]
+    @pl.when(n_blk > 0)
+    def _scan():
+        # phase 1: gather this bucket's S query rows from the compact plane
+        # (rows on an untiled leading axis: a one-row slice of a tiled axis
+        # is not a legal DMA window on the TPU)
+        def gather(s, carry):
+            cp = pltpu.make_async_copy(q_hbm.at[qb_ref[b, s]], q_s.at[s], sem_q)
+            cp.start()
+            cp.wait()
+            return carry
 
-    # phase 2: stream candidate blocks through a 2-deep VMEM ring
-    def copy_block(j, slot):
-        return pltpu.make_async_copy(vec_hbm.at[b, pl.ds(j * tc, tc)],
-                                     vbuf.at[slot], sem_vec.at[slot])
+        jax.lax.fori_loop(0, n_slots, gather, 0)
+        q = q_s[...].reshape(n_slots, -1).astype(jnp.float32)   # [S, d]
 
-    copy_block(0, 0).start()
+        # phase 2: stream candidate blocks through a 2-deep VMEM ring; every
+        # copy started is waited: block j+1's only when j+1 < n_blk
+        def copy_block(j, slot):
+            return pltpu.make_async_copy(vec_hbm.at[b, pl.ds(j * tc, tc)],
+                                         vbuf.at[slot], sem_vec.at[slot])
 
-    def body(j, carry):
-        slot = jax.lax.rem(j, 2)
+        copy_block(0, 0).start()
 
-        @pl.when(j + 1 < n_cblocks)
-        def _prefetch_next():
-            copy_block(j + 1, jax.lax.rem(j + 1, 2)).start()
+        def body(j, carry):
+            slot = jax.lax.rem(j, 2)
 
-        copy_block(j, slot).wait()
-        c = vbuf[slot].astype(jnp.float32)      # [TC, d]
-        cid = cid_ref[0, :, pl.ds(pl.multiple_of(j * tc, tc), tc)]   # [1, TC]
-        return merge_running(*carry, neg_sq_l2(q, c, cid), cid, k)
+            @pl.when(j + 1 < n_blk)
+            def _prefetch_next():
+                copy_block(j + 1, jax.lax.rem(j + 1, 2)).start()
 
-    init = running_init(n_slots, od_ref.shape[-1])
-    od_ref[0], oi_ref[0] = flush_running(*jax.lax.fori_loop(0, n_cblocks, body, init))
+            copy_block(j, slot).wait()
+            c = vbuf[slot].astype(jnp.float32)      # [TC, d]
+            cid = cid_ref[0, :, pl.ds(pl.multiple_of(j * tc, tc), tc)]   # [1, TC]
+            return merge_running(*carry, neg_sq_l2(q, c, cid), cid, k)
+
+        init = running_init(n_slots, width)
+        od_ref[0], oi_ref[0] = flush_running(*jax.lax.fori_loop(0, n_blk, body, init))
 
 
 @functools.partial(jax.jit, static_argnames=("k", "tc", "interpret"))
@@ -225,27 +237,31 @@ def l2_topk_qbuf(
     """Dispatch-buffer form of ``l2_topk_batched``: takes the compact
     ``q_pad`` plane plus ``qbuf`` indices instead of a host-expanded
     ``[B, S, d]`` query stack, so the staged operand footprint is
-    O(q_row·d) + O(B·S) indices rather than O(B·S·d). Rows for empty slots
-    (``qbuf == q_row``) compute against the sentinel query; callers mask
-    them out downstream exactly as with the expanded form."""
+    O(q_row·d) + O(B·S) indices rather than O(B·S·d).
+
+    Each bucket streams only its blocks of ``tc`` slots up to its last live
+    slot, and none without a query (``_util.live_blocks``, the second
+    scalar-prefetch operand). Rows of a bucket with no query come back
+    (inf, -1); empty slots of an occupied bucket compute against the
+    sentinel query. Callers drop both downstream, exactly as with the
+    expanded form."""
     bn, n_slots = qbuf.shape
     cn, d = cands.shape[1], cands.shape[2]
     assert cn % tc == 0, (cn, tc)
-    n_cblocks = cn // tc
+    n_blk = live_blocks(qbuf, cand_ids, tc, q_pad.shape[0] - 1)
     kp = lane_width(k)
-    kernel = functools.partial(_l2_topk_qbuf_kernel, k=k, tc=tc,
-                               n_cblocks=n_cblocks, n_slots=n_slots)
+    kernel = functools.partial(_l2_topk_qbuf_kernel, k=k, tc=tc, n_slots=n_slots)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(bn,),
         in_specs=[
             pl.BlockSpec(memory_space=pl.ANY),              # q_pad stays in HBM
             pl.BlockSpec(memory_space=pl.ANY),              # cands stay in HBM
-            pl.BlockSpec((1, 1, cn), lambda b, qb: (b, 0, 0)),  # cand_ids
+            pl.BlockSpec((1, 1, cn), lambda b, qb, nb: (b, 0, 0)),  # cand_ids
         ],
         out_specs=[
-            pl.BlockSpec((1, n_slots, kp), lambda b, qb: (b, 0, 0)),
-            pl.BlockSpec((1, n_slots, kp), lambda b, qb: (b, 0, 0)),
+            pl.BlockSpec((1, n_slots, kp), lambda b, qb, nb: (b, 0, 0)),
+            pl.BlockSpec((1, n_slots, kp), lambda b, qb, nb: (b, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((n_slots, 1, d), q_pad.dtype),
@@ -262,5 +278,6 @@ def l2_topk_qbuf(
             jax.ShapeDtypeStruct((bn, n_slots, kp), jnp.int32),
         ],
         interpret=interpret,
-    )(qbuf, q_pad.reshape(q_pad.shape[0], 1, d), cands, cand_ids.reshape(bn, 1, cn))
+    )(qbuf, n_blk, q_pad.reshape(q_pad.shape[0], 1, d), cands,
+      cand_ids.reshape(bn, 1, cn))
     return od[..., :k], oi[..., :k]

@@ -24,6 +24,7 @@ from repro.kernels import kmeans_assign as _km
 from repro.kernels import l2_topk as _l2
 from repro.kernels import pq_adc as _adc
 from repro.kernels import ref as _ref
+from repro.kernels._util import live_blocks  # noqa: F401
 from repro.kernels._util import pad_dim as _pad_dim, pad_rows as _pad_rows
 
 # Partition capacities are kept whole 128-lane tiles of slots: the qbuf scans
@@ -97,26 +98,43 @@ def l2_topk_batched(q, cands, cand_ids, k: int, *, impl: str | None = None,
     return d[:, :qn], i[:, :qn]
 
 
+def l2_qbuf_tile(cands_shape, k: int, tc: int | None = None) -> int:
+    """Vector-block tile ``l2_topk_qbuf`` streams a ``[B, C, d]`` store in:
+    ``tc``, or the autotune cache's (keyed on C/d/k), fitted to C."""
+    cn, d = cands_shape[1], cands_shape[2]
+    if tc is None:
+        tc = _autotune.lookup(_autotune.l2_key(cn, d, k))
+    return _slot_tile(tc, cn)
+
+
 def l2_topk_qbuf(q_pad, qbuf, cands, cand_ids, k: int, *,
                  impl: str | None = None, tc: int | None = None):
     """Dispatch-buffer top-k scan: compact ``q_pad`` [q_row+1, d] + ``qbuf``
     [B, S] indices vs [B, C, d] candidate sets → ([B, S, k], [B, S, k]).
     Replaces the host-side ``q_pad[qbuf]`` expansion — the kernel gathers each
     bucket's rows itself via scalar prefetch. ``tc=None`` consults the
-    measured-sweep autotune cache (keyed on the store shape C/d/k)."""
+    measured-sweep autotune cache (keyed on the store shape C/d/k). Each
+    bucket streams its ``live_blocks`` of that tile."""
     impl = impl or default_impl()
     qbuf = qbuf.astype(jnp.int32)
     if impl == "ref":
         return _ref.l2_topk_qbuf_ref(q_pad, qbuf, cands, cand_ids, k)
     interpret = _interpret(impl)
-    cn, d = cands.shape[1], cands.shape[2]
-    if tc is None:
-        tc = _autotune.lookup(_autotune.l2_key(cn, d, k))
-    tc_eff = _slot_tile(tc, cn)
+    tc_eff = l2_qbuf_tile(cands.shape, k, tc)
     cp = _pad_dim(cands, 1, tc_eff, 0.0)
     ip = _pad_dim(cand_ids.astype(jnp.int32), 1, tc_eff, -1)
     return _l2.l2_topk_qbuf(q_pad, qbuf, cp, ip, k, tc=tc_eff,
                             interpret=interpret)
+
+
+def pq_qbuf_tile(codes_shape, ks: int, k: int, tn: int | None = None) -> int:
+    """Code-block tile ``pq_adc_topk_qbuf`` streams a ``[B, N, m]`` code
+    plane in: ``tn``, or the autotune cache's (keyed on N/m/ks/k), fitted
+    to N."""
+    nn, m = codes_shape[1], codes_shape[2]
+    if tn is None:
+        tn = _autotune.lookup(_autotune.pq_adc_key(nn, m, ks, k))
+    return _slot_tile(tn, nn)
 
 
 def pq_adc_topk_qbuf(lut_pad, qbuf, codes, cand_ids, k: int, *, cand_off=None,
@@ -126,7 +144,8 @@ def pq_adc_topk_qbuf(lut_pad, qbuf, codes, cand_ids, k: int, *, cand_off=None,
     threading the residual ``cand_off`` [B, N] / ``q_off`` [B, S] offsets.
     Replaces the host-side ``lut_pad[qbuf]`` expansion (the O(B·S·m·ks)
     amplification); the kernel gathers each bucket's LUT rows via scalar
-    prefetch. ``tn=None`` consults the autotune cache (store shape N/m/ks/k)."""
+    prefetch. ``tn=None`` consults the autotune cache (store shape N/m/ks/k).
+    Each bucket streams its ``live_blocks`` of that tile."""
     impl = impl or default_impl()
     qbuf = qbuf.astype(jnp.int32)
     if impl == "ref":
@@ -134,11 +153,8 @@ def pq_adc_topk_qbuf(lut_pad, qbuf, codes, cand_ids, k: int, *, cand_off=None,
                                          cand_off=cand_off, q_off=q_off)
     interpret = _interpret(impl)
     bn, n_slots = qbuf.shape
-    nn, m = codes.shape[1], codes.shape[2]
-    ks = lut_pad.shape[2]
-    if tn is None:
-        tn = _autotune.lookup(_autotune.pq_adc_key(nn, m, ks, k))
-    tn_eff = _slot_tile(tn, nn)
+    nn = codes.shape[1]
+    tn_eff = pq_qbuf_tile(codes.shape, lut_pad.shape[2], k, tn)
     cp = _pad_dim(codes.astype(jnp.int32), 1, tn_eff, 0)
     ip = _pad_dim(cand_ids.astype(jnp.int32), 1, tn_eff, -1)
     if cand_off is None:

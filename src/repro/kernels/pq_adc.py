@@ -18,7 +18,9 @@ Three entry points:
     (``pltpu.PrefetchScalarGridSpec``), so each bucket's grid step DMAs only
     its own slots' LUT rows from HBM into VMEM — the host never materializes
     the ≈nprobe·q_cap_factor× amplified operand the old path staged — and the
-    codes stream through a double-buffered in-kernel pipeline.
+    codes stream through a double-buffered in-kernel pipeline. A second
+    scalar-prefetch operand gives each bucket its number of code blocks:
+    up to its last live slot, none for a bucket with no query.
 
 Tiling: grid = (Q_tiles, N_blocks); LUT tile [TQ, m·ks] stays in VMEM across
 the candidate scan, codes stream in as [m, TN] int blocks.
@@ -44,8 +46,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._util import (NEG_BIG, flush_running, lane_width, merge_running,
-                                 pad_dim, pad_rows as _pad_rows, running_init)
+from repro.kernels._util import (NEG_BIG, flush_running, lane_width, live_blocks,
+                                 merge_running, pad_dim, pad_rows as _pad_rows,
+                                 running_init)
 
 
 def adc_scores(lut, codes_t, ks: int):
@@ -264,12 +267,14 @@ def pq_adc_topk_batched(
     return od[:, :qn, :k], oi[:, :qn, :k]
 
 
-def _pq_adc_topk_qbuf_kernel(qb_ref, lut_hbm, codes_hbm, cid_ref, coff_ref,
+def _pq_adc_topk_qbuf_kernel(qb_ref, nb_ref, lut_hbm, codes_hbm, cid_ref, coff_ref,
                              qoff_ref, od_ref, oi_ref, lut_s, cbuf,
                              sem_lut, sem_codes,
-                             *, k: int, ks: int, tn: int, n_nblocks: int,
-                             n_slots: int):
-    """One bucket per grid step. Two-phase body:
+                             *, k: int, ks: int, tn: int, n_slots: int):
+    """One bucket per grid step, streaming its first ``nb_ref[b]`` code
+    blocks (``_util.live_blocks``: up to its last live slot; none for a
+    bucket with no query, whose rows come back (inf, -1) with no gather).
+    Two-phase body:
 
     1. scalar-prefetched LUT gather — ``qb_ref`` (SMEM) names each dispatch
        slot's query row; the rows are DMA'd one by one from the compact
@@ -281,41 +286,51 @@ def _pq_adc_topk_qbuf_kernel(qb_ref, lut_hbm, codes_hbm, cid_ref, coff_ref,
        DMA'd into the 2-deep ``cbuf`` ring; block j+1's copy is in flight
        while block j feeds the one-hot MXU contraction and the running
        top-k merge (carried through the fori_loop, no cross-step scratch).
+       Every copy started is waited: block j+1's starts only when
+       j+1 < ``nb_ref[b]``.
     """
     b = pl.program_id(0)
+    n_blk = nb_ref[b]
+    width = od_ref.shape[-1]
 
-    def gather(s, carry):
-        cp = pltpu.make_async_copy(lut_hbm.at[qb_ref[b, s]], lut_s.at[s],
-                                   sem_lut)
-        cp.start()
-        cp.wait()
-        return carry
+    @pl.when(n_blk == 0)
+    def _no_query():
+        od_ref[0], oi_ref[0] = flush_running(*running_init(n_slots, width))
 
-    jax.lax.fori_loop(0, n_slots, gather, 0)
-    lut = lut_s[...].reshape(n_slots, -1)       # [S, m·ks] f32
-    qoff = qoff_ref[0]                          # [S, 1] f32
+    @pl.when(n_blk > 0)
+    def _scan():
+        def gather(s, carry):
+            cp = pltpu.make_async_copy(lut_hbm.at[qb_ref[b, s]], lut_s.at[s],
+                                       sem_lut)
+            cp.start()
+            cp.wait()
+            return carry
 
-    def copy_block(j, slot):
-        return pltpu.make_async_copy(codes_hbm.at[b, :, pl.ds(j * tn, tn)],
-                                     cbuf.at[slot], sem_codes.at[slot])
+        jax.lax.fori_loop(0, n_slots, gather, 0)
+        lut = lut_s[...].reshape(n_slots, -1)       # [S, m·ks] f32
+        qoff = qoff_ref[0]                          # [S, 1] f32
 
-    copy_block(0, 0).start()
+        def copy_block(j, slot):
+            return pltpu.make_async_copy(codes_hbm.at[b, :, pl.ds(j * tn, tn)],
+                                         cbuf.at[slot], sem_codes.at[slot])
 
-    def body(j, carry):
-        slot = jax.lax.rem(j, 2)
+        copy_block(0, 0).start()
 
-        @pl.when(j + 1 < n_nblocks)
-        def _prefetch_next():
-            copy_block(j + 1, jax.lax.rem(j + 1, 2)).start()
+        def body(j, carry):
+            slot = jax.lax.rem(j, 2)
 
-        copy_block(j, slot).wait()
-        blk = pl.ds(pl.multiple_of(j * tn, tn), tn)
-        cid = cid_ref[0, :, blk]                # [1, tn] int32, -1 = padding
-        negd = _neg_adc(lut, cbuf[slot], cid, coff_ref[0, :, blk], qoff, ks)
-        return merge_running(*carry, negd, cid, k)
+            @pl.when(j + 1 < n_blk)
+            def _prefetch_next():
+                copy_block(j + 1, jax.lax.rem(j + 1, 2)).start()
 
-    init = running_init(n_slots, od_ref.shape[-1])
-    od_ref[0], oi_ref[0] = flush_running(*jax.lax.fori_loop(0, n_nblocks, body, init))
+            copy_block(j, slot).wait()
+            blk = pl.ds(pl.multiple_of(j * tn, tn), tn)
+            cid = cid_ref[0, :, blk]                # [1, tn] int32, -1 = padding
+            negd = _neg_adc(lut, cbuf[slot], cid, coff_ref[0, :, blk], qoff, ks)
+            return merge_running(*carry, negd, cid, k)
+
+        init = running_init(n_slots, width)
+        od_ref[0], oi_ref[0] = flush_running(*jax.lax.fori_loop(0, n_blk, body, init))
 
 
 @functools.partial(jax.jit, static_argnames=("k", "tn", "interpret"))
@@ -335,32 +350,36 @@ def pq_adc_topk_qbuf(
 
     Staged operand footprint is O(q_row·m·ks) + O(B·S) indices — independent
     of dispatch fan-out — instead of the O(B·S·m·ks) HBM stack the dense
-    batched kernel needs its caller to gather. Rows for empty slots
-    (``qbuf == q_row``) hold garbage; callers drop them, exactly like the
-    serve step's scatter. VMEM holds one bucket's gathered LUT rows
-    (S·m·ks·4 bytes) — S is the dispatch q_cap, small by construction.
+    batched kernel needs its caller to gather. Each bucket streams only its
+    blocks of ``tn`` slots up to its last live slot, and none without a
+    query (``_util.live_blocks``, the second scalar-prefetch operand). Rows
+    of a bucket with no query come back (inf, -1); empty slots of an
+    occupied bucket (``qbuf == q_row``) hold garbage. Callers drop both,
+    exactly like the serve step's scatter. VMEM holds one bucket's gathered
+    LUT rows (S·m·ks·4 bytes) — S is the dispatch q_cap, small by
+    construction.
     """
     bn, n_slots = qbuf.shape
     q_rows, m, ks = lut_pad.shape
     n = codes.shape[1]
     assert n % tn == 0, (n, tn)
-    n_nblocks = n // tn
+    n_blk = live_blocks(qbuf, cand_ids, tn, q_rows - 1)
     kp = lane_width(k)
     kernel = functools.partial(_pq_adc_topk_qbuf_kernel, k=k, ks=ks, tn=tn,
-                               n_nblocks=n_nblocks, n_slots=n_slots)
+                               n_slots=n_slots)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(bn,),
         in_specs=[
             pl.BlockSpec(memory_space=pl.ANY),                     # lut_pad (HBM)
             pl.BlockSpec(memory_space=pl.ANY),                     # codes (HBM)
-            pl.BlockSpec((1, 1, n), lambda b, qb: (b, 0, 0)),        # cand_ids
-            pl.BlockSpec((1, 1, n), lambda b, qb: (b, 0, 0)),        # cand_off
-            pl.BlockSpec((1, n_slots, 1), lambda b, qb: (b, 0, 0)),  # q_off
+            pl.BlockSpec((1, 1, n), lambda b, qb, nb: (b, 0, 0)),        # cand_ids
+            pl.BlockSpec((1, 1, n), lambda b, qb, nb: (b, 0, 0)),        # cand_off
+            pl.BlockSpec((1, n_slots, 1), lambda b, qb, nb: (b, 0, 0)),  # q_off
         ],
         out_specs=[
-            pl.BlockSpec((1, n_slots, kp), lambda b, qb: (b, 0, 0)),
-            pl.BlockSpec((1, n_slots, kp), lambda b, qb: (b, 0, 0)),
+            pl.BlockSpec((1, n_slots, kp), lambda b, qb, nb: (b, 0, 0)),
+            pl.BlockSpec((1, n_slots, kp), lambda b, qb, nb: (b, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((n_slots, 1, m * ks), jnp.float32),  # gathered LUT rows
@@ -377,7 +396,7 @@ def pq_adc_topk_qbuf(
             jax.ShapeDtypeStruct((bn, n_slots, kp), jnp.int32),
         ],
         interpret=interpret,
-    )(qbuf, lut_pad.reshape(q_rows, 1, m * ks), codes.transpose(0, 2, 1),
+    )(qbuf, n_blk, lut_pad.reshape(q_rows, 1, m * ks), codes.transpose(0, 2, 1),
       cand_ids.reshape(bn, 1, n), cand_off.reshape(bn, 1, n),
       q_off.reshape(bn, n_slots, 1))
     return od[..., :k], oi[..., :k]

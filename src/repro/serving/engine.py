@@ -197,8 +197,8 @@ def make_serve_step(cfg: LiraSystemConfig, mesh, n_queries: int, *, sigma: float
             ctx = tiers.ScanContext(q_loc=q_loc, q_pad=q_pad, cd=cd, b0=b0,
                                     b_loc=b_loc, k=k)
             scan_kw = tier.scan_kwargs(cfg, ctx, dict(zip(extra_fields, extras)))
-            dists, rids = scan.run(scan_impl, qbuf, q_pad, vecs_loc, ids_loc, k,
-                                   **scan_kw)
+            dists, rids, blocks = scan.run(scan_impl, qbuf, q_pad, vecs_loc,
+                                           ids_loc, k, **scan_kw)
 
         # ---- scatter back per query, local merge
         with jax.named_scope("lira.merge"):
@@ -241,9 +241,13 @@ def make_serve_step(cfg: LiraSystemConfig, mesh, n_queries: int, *, sigma: float
             with jax.named_scope("lira.merge"):
                 loc_d, loc_i = kops.dedup_topk(all_d, all_i, k, impl=scan_impl)
                 overflow = jax.lax.psum(overflow, "model")
+            blocks = jax.lax.psum(blocks, "model")
         nprobe_eff = probe_ok.sum(-1).astype(jnp.float32)
         if count_dedup:
-            return loc_d, loc_i, nprobe_eff, overflow[None], dedup_hits[None]
+            # with the dedup hits rides the scan's (blocks streamed, blocks
+            # of the whole capacity) pair: the engine books both
+            return (loc_d, loc_i, nprobe_eff, overflow[None], dedup_hits[None],
+                    blocks[None])
         return loc_d, loc_i, nprobe_eff, overflow[None]
 
     param_spec = jax.tree.map(lambda _: P(), probing_param_specs_cache(cfg))
@@ -254,7 +258,7 @@ def make_serve_step(cfg: LiraSystemConfig, mesh, n_queries: int, *, sigma: float
 
     out_specs = (P(bspec, None), P(bspec, None), P(bspec), P(bspec))
     if count_dedup:
-        out_specs = out_specs + (P(bspec),)
+        out_specs = out_specs + (P(bspec), P(bspec, None))
 
     def serve_step(params, store, queries, valid=None):
         if valid is None:
@@ -628,11 +632,12 @@ class LiraEngine:
                     out = fn(self.params, self.store, jnp.asarray(qp),
                              jnp.asarray(valid))
                 with tr.span("engine.wait"):
-                    d, i, npb, ovf, dups = jax.block_until_ready(out)
+                    d, i, npb, ovf, dups, blk = jax.block_until_ready(out)
             with tr.span("engine.post") as sp_post:
                 npb_np = np.asarray(npb)[:nq]
                 overflow = int(np.asarray(ovf).sum())
                 dedup_hits = int(np.asarray(dups).sum())
+                scan_blocks, dense_blocks = np.asarray(blk).sum(0).tolist()
                 dists = np.asarray(d)[:nq]
                 ids_np = np.asarray(i)[:nq]
             sp_root.set(tier=tier_obj.name, impl=impl, rows=nq)
@@ -659,6 +664,12 @@ class LiraEngine:
         m.counter("lira_engine_dedup_hits_total",
                   "replica-duplicate candidate slots merged away").inc(
                       dedup_hits, **lbl)
+        m.counter("lira_engine_scan_blocks_total",
+                  "candidate blocks the scan streamed (occupied partitions, "
+                  "up to each one's last live slot)").inc(scan_blocks, **lbl)
+        m.counter("lira_engine_scan_blocks_dense_total",
+                  "candidate blocks of every local partition's whole "
+                  "capacity").inc(dense_blocks, **lbl)
         m.counter("lira_engine_jit_cache_hits_total" if cache_hit
                   else "lira_engine_jit_cache_misses_total",
                   "serve-step jit cache").inc(**lbl)

@@ -72,19 +72,34 @@ def run(impl: str | None, qbuf, q_pad, vecs_loc, ids_loc, k: int, *,
     off_loc   [b_loc, q_row + 1]   — residual only: per-(partition, query)
                                      offsets, zero row for empty slots
 
-    Returns ([b_loc, q_cap, k] dists, [b_loc, q_cap, k] ids); rows for empty
-    slots hold garbage — the serve step's scatter drops them.
+    Returns ([b_loc, q_cap, k] dists, [b_loc, q_cap, k] ids, blocks); rows
+    for empty slots hold garbage — the serve step's scatter drops them.
+    ``blocks`` [2] int32 is (candidate blocks streamed, blocks of the whole
+    capacity) in the kernel's tile: the kernels stream each occupied
+    bucket's blocks up to its last live slot (``kops.live_blocks``), the
+    ``ref`` path scores every slot, so it reports the whole capacity.
     """
     impl = resolve_impl(impl)
+    cap = ids_loc.shape[1]
     if lut_pad is not None:
-        if impl == "ref":
-            return _quantized_ref(qbuf, q_pad, vecs_loc, ids_loc, k,
-                                  lut_pad, codes_loc, rk, cterm_loc, off_loc)
-        return _quantized_kernel(qbuf, q_pad, vecs_loc, ids_loc, k,
-                                 lut_pad, codes_loc, rk, cterm_loc, off_loc, impl)
+        tile = kops.pq_qbuf_tile(codes_loc.shape, lut_pad.shape[2], rk)
+    else:
+        tile = kops.l2_qbuf_tile(vecs_loc.shape, k)
+    dense = ids_loc.shape[0] * -(-cap // tile)
     if impl == "ref":
-        return _f32_ref(qbuf, q_pad, vecs_loc, ids_loc, k)
-    return _f32_kernel(qbuf, q_pad, vecs_loc, ids_loc, k, impl)
+        if lut_pad is not None:
+            d, i = _quantized_ref(qbuf, q_pad, vecs_loc, ids_loc, k,
+                                  lut_pad, codes_loc, rk, cterm_loc, off_loc)
+        else:
+            d, i = _f32_ref(qbuf, q_pad, vecs_loc, ids_loc, k)
+        return d, i, jnp.array([dense, dense], jnp.int32)
+    if lut_pad is not None:
+        d, i = _quantized_kernel(qbuf, q_pad, vecs_loc, ids_loc, k, lut_pad,
+                                 codes_loc, rk, cterm_loc, off_loc, impl, tile)
+    else:
+        d, i = _f32_kernel(qbuf, q_pad, vecs_loc, ids_loc, k, impl, tile)
+    streamed = kops.live_blocks(qbuf, ids_loc, tile, q_pad.shape[0] - 1).sum()
+    return d, i, jnp.stack([streamed, jnp.int32(dense)])
 
 
 # ------------------------------------------------------------------ f32 tier
@@ -111,12 +126,13 @@ def _f32_ref(qbuf, q_pad, vecs_loc, ids_loc, k):
     return jax.lax.map(scan_partition, (qbuf, vecs_loc, ids_loc))
 
 
-def _f32_kernel(qbuf, q_pad, vecs_loc, ids_loc, k, impl):
+def _f32_kernel(qbuf, q_pad, vecs_loc, ids_loc, k, impl, tile):
     # cast the COMPACT plane to the store dtype (same quantization point as
     # the ref path's per-slot cast); the kernel gathers each bucket's rows
     # itself via the scalar-prefetched qbuf — no [b_loc, q_cap, d] expansion
     qp = q_pad.astype(vecs_loc.dtype)                        # [q_row + 1, d]
-    return kops.l2_topk_qbuf(qp, qbuf, vecs_loc, ids_loc, k, impl=impl)
+    return kops.l2_topk_qbuf(qp, qbuf, vecs_loc, ids_loc, k, impl=impl,
+                             tc=tile)
 
 
 # ------------------------------------------------------------ quantized tiers
@@ -161,7 +177,7 @@ def _quantized_ref(qbuf, q_pad, vecs_loc, ids_loc, k, lut_pad, codes_loc, rk,
 
 
 def _quantized_kernel(qbuf, q_pad, vecs_loc, ids_loc, k, lut_pad, codes_loc, rk,
-                      cterm_loc, off_loc, impl):
+                      cterm_loc, off_loc, impl, tile):
     b_loc, _ = qbuf.shape
     cap = vecs_loc.shape[1]
     # stage 1: one fused launch over all buckets. The kernel ranks by ADC and
@@ -177,7 +193,8 @@ def _quantized_kernel(qbuf, q_pad, vecs_loc, ids_loc, k, lut_pad, codes_loc, rk,
         coff = cterm_loc                                     # [b_loc, cap]
         qoff = jnp.take_along_axis(off_loc, qbuf, axis=1)    # [b_loc, q_cap]
     _, sl = kops.pq_adc_topk_qbuf(lut_pad, qbuf, codes_loc, slots, rk,
-                                  cand_off=coff, q_off=qoff, impl=impl)
+                                  cand_off=coff, q_off=qoff, impl=impl,
+                                  tn=tile)
     # stage 2: exact f32 rerank of the shortlist (same math as the ref path)
     safe = jnp.maximum(sl, 0)                                # [b_loc, q_cap, rk]
     cid = jnp.where(sl >= 0,

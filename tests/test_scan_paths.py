@@ -75,7 +75,7 @@ def test_scan_f32_matches_numpy_twin(scan_inputs, impl):
         with pytest.raises(ValueError, match="interpret mode"):
             run()
         return
-    d, i = run()
+    d, i, _ = run()
     d_np, i_np = scan_np(qbuf, q_pad, vecs, ids, k)
     _assert_scan_matches_np(d, i, d_np, i_np, qbuf, q_pad.shape[0] - 1)
 
@@ -84,8 +84,8 @@ def test_scan_f32_kernel_bit_identical_to_ref(scan_inputs):
     qbuf, q_pad, vecs, ids = scan_inputs
     args = (jnp.asarray(qbuf), jnp.asarray(q_pad), jnp.asarray(vecs),
             jnp.asarray(ids), 7)
-    d_ref, i_ref = scan.run("ref", *args)
-    d_ker, i_ker = scan.run("interpret", *args)
+    d_ref, i_ref, _ = scan.run("ref", *args)
+    d_ker, i_ker, _ = scan.run("interpret", *args)
     occupied = qbuf < q_pad.shape[0] - 1
     np.testing.assert_array_equal(np.asarray(d_ref)[occupied], np.asarray(d_ker)[occupied])
     np.testing.assert_array_equal(np.asarray(i_ref)[occupied], np.asarray(i_ker)[occupied])
@@ -109,10 +109,10 @@ def test_scan_quantized_matches_numpy_twin(scan_inputs, impl, residual):
         off = np.concatenate([host.normal(0, 1, (b_loc, q_row)),
                               np.zeros((b_loc, 1))], 1).astype(np.float32)
         kwargs = {"cterm_loc": jnp.asarray(cterm), "off_loc": jnp.asarray(off)}
-    d, i = scan.run(impl, jnp.asarray(qbuf), jnp.asarray(q_pad),
-                    jnp.asarray(vecs), jnp.asarray(ids), k,
-                    lut_pad=jnp.asarray(lut_pad), codes_loc=jnp.asarray(codes),
-                    rk=rk, **kwargs)
+    d, i, _ = scan.run(impl, jnp.asarray(qbuf), jnp.asarray(q_pad),
+                       jnp.asarray(vecs), jnp.asarray(ids), k,
+                       lut_pad=jnp.asarray(lut_pad), codes_loc=jnp.asarray(codes),
+                       rk=rk, **kwargs)
     d_np, i_np = scan_np(qbuf, q_pad, vecs, ids, k, lut_pad=lut_pad,
                          codes=codes, rk=rk, cterm=cterm, off=off)
     _assert_scan_matches_np(d, i, d_np, i_np, qbuf, q_row)

@@ -8,6 +8,9 @@ empty buckets (every slot ``q_row``), and degenerate k > cap pools; the
 autotuner's cache-key path; and the bytes-accounting gates: the staged
 operand footprint no longer scales with occupied dispatch slots, and the
 traced quantized scan contains no ``[b_loc, q_cap, m, ks]`` intermediate.
+Then the per-bucket trip count: each bucket streams only its blocks up to
+its last live slot, and none without a query, bit-equal to the
+full-capacity scan (``_qbuf_dense_oracle``), and the engine's block counters.
 """
 import re
 
@@ -16,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _qbuf_dense_oracle import l2_topk_qbuf_dense, pq_adc_topk_qbuf_dense
 from repro.kernels import autotune, ops, ref
 from repro.serving import scan
 
@@ -226,3 +230,223 @@ def test_quantized_scan_traces_without_expanded_lut(qbuf_inputs):
         "quantized scan re-materializes the per-slot LUT expansion")
     # while the compact plane is still there
     assert f"f32[{QR + 1},{M},{KS}]" in str(jaxpr)
+
+
+# --------------------------------------------- live blocks: per-bucket trips
+
+LB, LS, LQR, LCAP, LTILE, LK = 4, 5, 9, 64, 16, 6
+LIVE_CASES = ["unoccupied", "no_live_slots", "full", "mid_tile", "tombstones",
+              "grown_by_insert"]
+
+
+def _live_ids(extents, rng, cap=LCAP):
+    """[len(extents), cap] ids, live from slot 0 up to each extent."""
+    ids = np.full((len(extents), cap), -1, np.int32)
+    for b, e in enumerate(extents):
+        ids[b, :e] = rng.permutation(10_000)[:e]
+    return ids
+
+
+def _live_case(case, rng):
+    """Dispatch shapes for one case: (qbuf, cand ids) and the blocks each
+    bucket must stream; queries are packed from slot 0 as dispatch packs
+    them, bucket 1 half full."""
+    qbuf = rng.integers(0, LQR, (LB, LS)).astype(np.int32)
+    qbuf[1, 2:] = LQR
+    ids = _live_ids([40, 23, 64, 9], rng)
+    if case == "unoccupied":
+        qbuf[0] = qbuf[2] = LQR
+        n_blk = [0, 2, 0, 1]
+    elif case == "no_live_slots":
+        ids[1] = -1
+        n_blk = [3, 0, 4, 1]
+    elif case == "full":
+        ids = _live_ids([LCAP] * LB, rng)
+        n_blk = [4] * LB
+    elif case == "mid_tile":
+        ids = _live_ids([37, 5, 50, 17], rng)
+        n_blk = [3, 1, 4, 2]
+    else:  # tombstones: holes below the extent, one a whole block
+        ids = _live_ids([60, 60, 33, 64], rng)
+        ids[0, 16:32] = -1
+        ids[1, rng.choice(59, 25, replace=False)] = -1
+        ids[2, 1:32] = -1
+        ids[3, ::3] = -1
+        n_blk = [4, 4, 3, 4]
+    return qbuf, ids, np.array(n_blk, np.int32)
+
+
+@pytest.fixture(scope="module")
+def grown_store():
+    """Id plane and vectors of a store grown by ``insert`` after its build:
+    the grown partitions' live slots run past the build's capacity."""
+    from repro.data import make_vector_dataset
+    from repro.launch.mesh import make_test_mesh
+    from repro.serving import BuildConfig, LiraEngine
+
+    ds = make_vector_dataset(n=400, n_queries=4, dim=16, n_modes=8, seed=5)
+    eng = LiraEngine.build(make_test_mesh(), ds.base,
+                           BuildConfig(n_partitions=LB, k=LK, train_frac=0.5,
+                                       epochs=1, nprobe_max=LB))
+    cap0 = eng.cfg.capacity
+    c0 = np.asarray(eng.store["centroids"])[0]
+    n_new = cap0 * LB + 1                     # more rows than the store holds
+    x_new = c0 + np.random.default_rng(1).normal(0, 0.01, (n_new, 16)).astype(np.float32)
+    eng.insert(x_new, np.arange(n_new) + 10_000)
+    assert eng.cfg.capacity > cap0
+    occ = np.asarray(eng.store["occupancy"])
+    ids = np.where(occ, np.asarray(eng.store["ids"]), -1)
+    assert (occ[:, cap0:].any(1)).any()
+    return ids, np.asarray(eng.store["vectors"])
+
+
+def _case_arrays(case, grown_store):
+    rng = np.random.default_rng(LIVE_CASES.index(case))
+    if case == "grown_by_insert":
+        ids, vecs = grown_store
+        qbuf = rng.integers(0, LQR, (ids.shape[0], LS)).astype(np.int32)
+        qbuf[1, 1:] = LQR
+        extent = np.array([np.flatnonzero(r >= 0).max() + 1 if (r >= 0).any() else 0
+                           for r in ids])
+        n_blk = -(-extent // LTILE)
+    else:
+        qbuf, ids, n_blk = _live_case(case, rng)
+        vecs = rng.standard_normal((LB, LCAP, 16)).astype(np.float32)
+    b, cap = ids.shape
+    q_pad = rng.standard_normal((LQR + 1, 16)).astype(np.float32)
+    q_pad[LQR] = 1e9
+    lut_pad = rng.standard_normal((LQR + 1, 4, 16)).astype(np.float32)
+    lut_pad[LQR] = 0.0
+    codes = rng.integers(0, 16, (b, cap, 4)).astype(np.int32)
+    coff = rng.standard_normal((b, cap)).astype(np.float32)
+    qoff = rng.standard_normal((b, LS)).astype(np.float32)
+    return dict(qbuf=qbuf, ids=ids, n_blk=n_blk, vecs=vecs, q_pad=q_pad,
+                lut_pad=lut_pad, codes=codes, coff=coff, qoff=qoff)
+
+
+@pytest.mark.parametrize("case", LIVE_CASES)
+@pytest.mark.parametrize("kernel", ["l2", "adc"])
+def test_live_block_scan_bit_equal_to_full_capacity_scan(kernel, case, request):
+    """Streaming each bucket only up to its last live slot, and nothing for
+    a bucket without a query, returns on every occupied slot exactly what
+    the full-capacity scan returned (the oracle keeps that kernel as it
+    was), dists and ids bit for bit; a bucket without a query comes back
+    (inf, -1)."""
+    grown = request.getfixturevalue("grown_store") if case == "grown_by_insert" else None
+    x = {n: jnp.asarray(a) for n, a in _case_arrays(case, grown).items()}
+    if kernel == "l2":
+        new = ops.l2_topk_qbuf(x["q_pad"], x["qbuf"], x["vecs"], x["ids"], LK,
+                               impl="interpret", tc=LTILE)
+        old = l2_topk_qbuf_dense(x["q_pad"], x["qbuf"], x["vecs"], x["ids"], LK,
+                                 tc=LTILE)
+    else:
+        kw = dict(cand_off=x["coff"], q_off=x["qoff"])
+        new = ops.pq_adc_topk_qbuf(x["lut_pad"], x["qbuf"], x["codes"], x["ids"],
+                                   LK, impl="interpret", tn=LTILE, **kw)
+        old = pq_adc_topk_qbuf_dense(x["lut_pad"], x["qbuf"], x["codes"],
+                                     x["ids"], LK, tn=LTILE, **kw)
+    occ = np.asarray(x["qbuf"]) < LQR
+    for a, b in zip(new, old):
+        np.testing.assert_array_equal(np.asarray(a)[occ], np.asarray(b)[occ])
+    no_query = ~occ.any(1)
+    assert np.isinf(np.asarray(new[0])[no_query]).all()
+    assert (np.asarray(new[1])[no_query] == -1).all()
+    np.testing.assert_array_equal(
+        np.asarray(ops.live_blocks(x["qbuf"], x["ids"], LTILE, LQR)),
+        np.where(occ.any(1), x["n_blk"], 0))
+
+
+def test_live_blocks_counts_tiles_up_to_the_last_live_slot():
+    qbuf = jnp.array([[0, 3, 3], [3, 3, 3], [2, 3, 3], [1, 0, 2], [0, 3, 3]])
+    ids = -jnp.ones((5, 40), jnp.int32)
+    ids = ids.at[0, 0].set(7)          # one live slot: one block
+    ids = ids.at[1, :].set(1)          # live, but no query: none
+    ids = ids.at[2, 15].set(4)         # last slot of block 1
+    ids = ids.at[2, 16].set(-1)
+    ids = ids.at[3, 16].set(5)         # first slot of block 2
+    ids = ids.at[3, 39].set(9)         # last slot of a ragged capacity
+    # bucket 4: a query and no live slot: none
+    got = ops.live_blocks(qbuf, ids, 8, 3)
+    assert got.dtype == jnp.int32
+    assert got.tolist() == [1, 0, 2, 5, 0]
+    assert ops.live_blocks(qbuf, ids, 16, 3).tolist() == [1, 0, 1, 3, 0]
+
+
+def _step_store(rng, extents, cap=384, dim=16):
+    b = len(extents)
+    ids = _live_ids(extents, rng, cap)
+    vecs = rng.standard_normal((b, cap, dim)).astype(np.float32)
+    return {"centroids": jnp.asarray(vecs.mean(1)), "vectors": jnp.asarray(vecs),
+            "ids": jnp.asarray(ids), "occupancy": jnp.asarray(ids >= 0)}
+
+
+def _step_cfg(b, cap=384, dim=16):
+    from repro.configs.base import LiraSystemConfig
+
+    return LiraSystemConfig(arch="t", dim=dim, n_partitions=b, capacity=cap, k=5,
+                            nprobe_max=b)
+
+
+def test_serve_step_block_counter_sums_occupied_buckets():
+    """Each query probes only its best partition (σ above any probability):
+    the engine's block counter is the live blocks of exactly the partitions
+    some query probed, the dense counter every partition's capacity."""
+    from repro.core import probing
+    from repro.kernels import ops as kops
+    from repro.launch.mesh import make_test_mesh
+    from repro.obs.metrics import MetricsRegistry
+    from repro.serving import LiraEngine, SearchRequest
+
+    rng = np.random.default_rng(3)
+    extents = [384, 130, 0, 7, 200, 300]
+    store = _step_store(rng, extents)
+    cfg = _step_cfg(len(extents))
+    params = probing.init(jax.random.PRNGKey(2),
+                          probing.ProbingConfig(dim=16, n_partitions=len(extents)))
+    reg = MetricsRegistry()
+    eng = LiraEngine(cfg=cfg, params=params, store=store, mesh=make_test_mesh(),
+                     metrics=reg)
+    q = rng.standard_normal((5, 16)).astype(np.float32)
+    res = eng.search(SearchRequest(queries=q, sigma=2.0, impl="interpret"))
+    assert (res.nprobe_eff == 1).all()
+    cents = store["centroids"]
+    cd = (jnp.sum(q * q, -1, keepdims=True)
+          - 2.0 * jnp.dot(q, cents.T, precision=jax.lax.Precision.HIGHEST)
+          + jnp.sum(cents * cents, -1)[None, :])
+    probed = set(np.argmax(np.asarray(probing.apply(params, jnp.asarray(q), cd)), 1).tolist())
+    tile = kops.l2_qbuf_tile(store["vectors"].shape, cfg.k)
+    expect = sum(-(-extents[b] // tile) for b in probed)
+    blocks = reg.counter("lira_engine_scan_blocks_total")
+    dense = reg.counter("lira_engine_scan_blocks_dense_total")
+    assert blocks.total() == expect
+    assert blocks.value(tier="f32", impl="interpret") == expect
+    assert dense.total() == len(extents) * -(-384 // tile)
+    assert len(probed) < len(extents)          # some partitions had no query
+
+
+@pytest.mark.parametrize("impl", ["ref", "interpret"])
+def test_full_store_streams_every_block(impl):
+    """A store filled to capacity, every partition probed, streams exactly
+    the blocks of the whole capacity (what the scan streamed before it
+    skipped any); a batch with no valid row streams none on the kernel path.
+    The ``ref`` path scores every slot and always reports the whole
+    capacity."""
+    from repro.core import probing
+    from repro.kernels import ops as kops
+    from repro.launch.mesh import make_test_mesh
+    from repro.serving.engine import make_serve_step
+
+    rng = np.random.default_rng(4)
+    b = 4
+    store = _step_store(rng, [384] * b)
+    cfg = _step_cfg(b)
+    params = probing.init(jax.random.PRNGKey(0),
+                          probing.ProbingConfig(dim=16, n_partitions=b))
+    step = jax.jit(make_serve_step(cfg, make_test_mesh(), 8, sigma=-1.0, impl=impl,
+                                   count_dedup=True))
+    q = jnp.asarray(rng.standard_normal((8, 16)).astype(np.float32))
+    dense = b * -(-384 // kops.l2_qbuf_tile(store["vectors"].shape, cfg.k))
+    *_, blocks = step(params, store, q, jnp.ones((8,), bool))
+    assert np.asarray(blocks).tolist() == [[dense, dense]]
+    *_, blocks = step(params, store, q, jnp.zeros((8,), bool))
+    assert np.asarray(blocks).tolist() == [[0 if impl == "interpret" else dense, dense]]

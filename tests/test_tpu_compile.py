@@ -65,6 +65,15 @@ def _compile(fn, *args):
     return jax.jit(fn).lower(*args).compile()
 
 
+def _streams_live_blocks(text: str, kernel: str, b_loc: int) -> bool:
+    """Whether the compiled ``kernel`` takes its per-bucket block counts:
+    ``[b_loc]`` int32 beside the ``[b_loc, q_cap]`` dispatch buffer, its
+    two scalar-prefetch operands."""
+    return re.search(rf"%{kernel}(\.\d+)? = .*operand_layout_constraints="
+                     rf"\{{s32\[{b_loc},\d+\]\{{1,0\}}, s32\[{b_loc}\]\{{0\}}",
+                     text) is not None
+
+
 def test_l2_topk_qbuf_compiles(one_chip):
     on = SingleDeviceSharding(one_chip)
     compiled = _compile(
@@ -73,7 +82,7 @@ def test_l2_topk_qbuf_compiles(one_chip):
         _sds((B, Q_CAP), jnp.int32, on),
         _sds((B, CAP, D), jnp.float32, on),
         _sds((B, CAP), jnp.int32, on))
-    assert "tpu_custom_call" in compiled.as_text()
+    assert _streams_live_blocks(compiled.as_text(), "l2_topk_qbuf", B)
 
 
 def test_pq_adc_topk_qbuf_compiles_with_residual_offsets(one_chip):
@@ -87,7 +96,7 @@ def test_pq_adc_topk_qbuf_compiles_with_residual_offsets(one_chip):
         _sds((B, CAP), jnp.int32, on),
         _sds((B, CAP), jnp.float32, on),
         _sds((B, Q_CAP), jnp.float32, on))
-    assert "tpu_custom_call" in compiled.as_text()
+    assert _streams_live_blocks(compiled.as_text(), "pq_adc_topk_qbuf", B)
 
 
 def test_dedup_topk_compiles_at_serve_pool_width(one_chip):
@@ -100,14 +109,15 @@ def test_dedup_topk_compiles_at_serve_pool_width(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def _compile_serve_step(devices, model: int, *, tier: str = "residual_pq", cap: int = CAP):
+def _compile_serve_step(devices, model: int, *, tier: str = "residual_pq", cap: int = CAP,
+                        n_queries: int = N_QUERIES):
     """The whole jitted serve step, Mosaic kernels and all, at the largest
-    bucket the smoke serves, on a (data=1, model) mesh."""
+    bucket the smoke serves (or ``n_queries``), on a (data=1, model) mesh."""
     import dataclasses
 
     cfg = dataclasses.replace(CONFIG_QUANTIZED, capacity=cap, tier=tier)
     mesh = Mesh(np.array(devices[:model]).reshape(1, model), ("data", "model"))
-    step = make_serve_step(cfg, mesh, N_QUERIES, tier=tier, impl="pallas",
+    step = make_serve_step(cfg, mesh, n_queries, tier=tier, impl="pallas",
                            count_dedup=True)
     rep = NamedSharding(mesh, P())
     params = jax.tree.map(lambda s: _sds(s.shape, s.dtype, rep),
@@ -116,8 +126,8 @@ def _compile_serve_step(devices, model: int, *, tier: str = "residual_pq", cap: 
     store = {n: _sds(s.shape, s.dtype, NamedSharding(mesh, pspecs[n]))
              for n, s in store_specs(cfg).items()}
     return _compile(step, params, store,
-                    _sds((N_QUERIES, D), jnp.float32, rep),
-                    _sds((N_QUERIES,), jnp.bool_, rep))
+                    _sds((n_queries, D), jnp.float32, rep),
+                    _sds((n_queries,), jnp.bool_, rep))
 
 
 def _device_bytes(compiled) -> int:
@@ -129,7 +139,19 @@ def _device_bytes(compiled) -> int:
 def test_residual_pq_serve_step_compiles_and_fits_hbm(one_chip):
     compiled = _compile_serve_step([one_chip], 1)
     assert compiled.as_text().count("tpu_custom_call") >= 2  # scan + merge kernels
+    assert _streams_live_blocks(compiled.as_text(), "pq_adc_topk_qbuf", B)
     assert _device_bytes(compiled) < HBM_BYTES, compiled.memory_analysis()
+
+
+def test_f32_serve_step_at_the_batch_bucket_fits_hbm(one_chip):
+    """The f32 step at a 1,024-query bucket over a SIFT1M-sized store
+    (12,800 slots per partition): the scan takes its block counts and the
+    step's temporaries stay what the full-capacity scan needed (1.59 GB)."""
+    compiled = _compile_serve_step([one_chip], 1, tier="f32", cap=12800, n_queries=1024)
+    assert _streams_live_blocks(compiled.as_text(), "l2_topk_qbuf", B)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1.7e9, mem
+    assert _device_bytes(compiled) < HBM_BYTES, mem
 
 
 def test_f32_serve_step_scans_a_lane_aligned_store_in_place(one_chip):
@@ -147,6 +169,8 @@ def test_model_sharded_serve_step_compiles_on_four_chips(topo):
     compiled = _compile_serve_step(topo.devices, 4)
     text = compiled.as_text()
     assert "all-gather" in text
+    # each chip counts blocks over its own quarter of the partitions
+    assert _streams_live_blocks(text, "pq_adc_topk_qbuf", B // 4)
     assert text.count("tpu_custom_call") >= 3  # scan + local merge + cross-shard merge
     # the dedup counter, local and cross-shard, sits beside the merge scope
     tele = re.findall(r'op_name="([^"]*lira\.telemetry[^"]*)"', text)
