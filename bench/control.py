@@ -35,19 +35,20 @@ sys.path.insert(0, str(ROOT / "bench"))
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
 
-def readings(mix: dict, seed: int, n_queries: int, base, base_np, pool_np) -> dict:
-    """The numbers a run compares, for brute-force answers in three passes
-    (``control``) and at HIGHEST (``highest``, which is also the ground
-    truth of ``recall_miss``)."""
+def readings(mix: dict, seed: int, n_queries: int, base, base_np, pool_np,
+             metric: str) -> dict:
+    """The numbers a run compares, for brute-force answers under ``metric``
+    in three passes (``control``) and at HIGHEST (``highest``, which is also
+    the ground truth of ``recall_miss``)."""
     from lirabench import reference, traffic
 
     rows = traffic.query_rows(mix, len(pool_np), n_queries, seed)
     q = pool_np[rows]
     out, gt = {}, None
     for name, passes in (("highest", 6), ("control", 3)):
-        dists, ids = reference.knn(q, base, int(mix["k"]), passes=passes)
+        dists, ids = reference.knn(q, base, int(mix["k"]), metric=metric, passes=passes)
         gt = ids if gt is None else gt
-        out[name] = {"dist_gap": reference.dist_gap(q, ids, dists, base_np),
+        out[name] = {"dist_gap": reference.dist_gap(q, ids, dists, base_np, metric=metric),
                      "bad_answers": reference.bad_answers(ids, dists, len(base_np)),
                      "recall_miss": 1.0 - reference.recall(ids, gt)}
     return out
@@ -75,18 +76,20 @@ def main() -> int:
     if args.fault:
         for seed in args.seeds:
             res = harness.run_cell(ROOT, bench, args.workload, seed, args.seconds, False,
-                                   jax.devices()[0], fault=faults.FAULTS[args.fault]())
+                                   jax.devices(), fault=faults.FAULTS[args.fault]())
             print(json.dumps({"seed": seed, "fault": args.fault, **res}), flush=True)
         return 0
     cell = harness.find(bench["workloads"], args.workload)
     cfg = harness.load_json(ROOT / harness.find(bench["configs"], cell["config"])["file"])
     mix = harness.load_json(ROOT / "bench" / "traffic" / f"{cell['traffic']}.json")
     limits = harness.load_json(ROOT / "bench" / "limits" / f"{args.workload}.json")
-    base, pool = corpus.make_corpus(cfg["dataset"])
+    harness.check_declared(cfg)
+    base, pool, _ = corpus.make_corpus(cfg["dataset"])
     base_np, pool_np = np.asarray(base), np.asarray(pool)
     for seed in args.seeds:
         out = {"seed": seed, "queries": args.queries, "limits": limits}
-        out.update(readings(mix, seed, args.queries, base, base_np, pool_np))
+        out.update(readings(mix, seed, args.queries, base, base_np, pool_np,
+                            cfg["dataset"]["metric"]))
         out["control_rejected"] = any(out["control"][k] > v for k, v in limits.items())
         print(json.dumps(out), flush=True)
     return 0

@@ -11,6 +11,32 @@ The corpus is fixed by the configuration's ``data_seed``: every run of a
 configuration serves the same index, as every user of SIFT1M searches the
 same million vectors with the same 10,000 test queries. A run's ``--seed``
 chooses the traffic over that pool (see ``traffic.py``).
+
+Without a ``dataset.queries`` section the query pool is the tail of the
+base's own draw: queries from the distribution of the corpus, as SIFT1M's
+are. With ``{"dist": "shifted", "offset": L, "n_learn": n}`` the queries come
+from another distribution, as text queries against image embeddings do in
+big-ann-benchmarks' Yandex Text-to-Image: the same topics as the base (its
+mode centres and per-mode spreads), with
+
+* mode popularity drawn again from an independent key, so the topics
+  queried most are not those the base holds most of;
+* the whole query cloud moved by one fixed vector of length ``offset`` in a
+  direction drawn from the seed, the gap between two modalities' embeddings
+  in a joint space;
+* no points between modes and no uniform floor.
+
+From that one distribution come the test pool (``n_queries`` rows) and a
+separate learn pool (``n_learn`` rows, none if absent), disjoint draws, the
+learn pool for training the probing model on queries such as users send. The
+base is the same draw as without the section. The recipe is the benchmark's
+stand-in for data nothing here can download; a configuration that uses it
+lists it under ``assumed``. It is not calibrated: no published statistic of
+Text-to-Image backs the shape of the shift or any ``offset``. A configuration
+that adopts it first sets ``offset`` from a figure big-ann-benchmarks or
+arXiv:2205.03763 publishes for the dataset (such as a query's distance to its
+nearest base vector against a base vector's to its nearest other one), and
+cites that figure under ``assumed``.
 """
 from __future__ import annotations
 
@@ -19,6 +45,29 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+QUERY_DISTS = ("shifted",)
+_QUERY_KEYS = ("dist", "offset", "n_learn")
+
+
+def check(ds: dict) -> None:
+    """Refuse a ``dataset`` section that declares what this module does not
+    make: another dtype than float32, an unknown query distribution or key."""
+    if ds.get("dtype") != "float32":
+        raise ValueError(f"dataset.dtype {ds.get('dtype')!r} is not implemented (float32)")
+    qs = ds.get("queries")
+    if qs is None:
+        return
+    if qs.get("dist") not in QUERY_DISTS:
+        raise ValueError(f"dataset.queries.dist {qs.get('dist')!r} is not implemented "
+                         f"(one of {QUERY_DISTS})")
+    for key in qs:
+        if key not in _QUERY_KEYS:
+            raise ValueError(f"dataset.queries.{key} is not implemented (keys {_QUERY_KEYS})")
+    if not float(qs.get("offset", -1.0)) >= 0.0:
+        raise ValueError(f"dataset.queries.offset {qs.get('offset')!r} must be a length >= 0")
+    if int(qs.get("n_learn", 0)) < 0:
+        raise ValueError(f"dataset.queries.n_learn {qs['n_learn']!r} must be >= 0")
 
 
 def _sizes(ds: dict) -> tuple[int, int, int]:
@@ -59,15 +108,39 @@ def _mixture(key, *, total, n_bound, n_noise, dim, n_modes, center_scale, spread
     return x[jax.random.permutation(k[10], total)]
 
 
+@functools.partial(jax.jit,
+                   static_argnames=("n", "dim", "n_modes", "center_scale", "spread", "offset"))
+def _shifted(key, *, n, dim, n_modes, center_scale, spread, offset):
+    # the base's mode centres and spreads: _mixture's first two draws
+    k = jax.random.split(key, 12)
+    centers = jax.random.normal(k[0], (n_modes, dim), jnp.float32) * center_scale
+    scales = (0.3 + jax.random.gamma(k[1], 2.0, (n_modes, dim)) * 0.25) * spread
+    kq = jax.random.split(jax.random.fold_in(key, 1), 4)
+    weights = jax.random.pareto(kq[0], 1.5, (n_modes,)) - 1.0 + 0.05
+    modes = jax.random.categorical(kq[1], jnp.log(weights / weights.sum()), shape=(n,))
+    direction = jax.random.normal(kq[2], (dim,), jnp.float32)
+    shift = direction / jnp.linalg.norm(direction) * offset
+    y = centers[modes] + jax.random.normal(kq[3], (n, dim)) * scales[modes] + shift
+    return y.astype(jnp.float32)
+
+
 def make_corpus(ds: dict):
-    """(base [n_base, dim], queries [n_queries, dim]) as float32 device arrays,
-    from the configuration's ``dataset`` section."""
+    """(base [n_base, dim], queries [n_queries, dim], learn [n_learn, dim] or
+    None) as float32 device arrays, from the configuration's ``dataset``
+    section."""
+    check(ds)
     total, n_bound, n_noise = _sizes(ds)
-    x = _mixture(jax.random.PRNGKey(int(ds["data_seed"])), total=total, n_bound=n_bound,
-                 n_noise=n_noise, dim=int(ds["dim"]), n_modes=int(ds["n_modes"]),
+    key = jax.random.PRNGKey(int(ds["data_seed"]))
+    shape = dict(dim=int(ds["dim"]), n_modes=int(ds["n_modes"]),
                  center_scale=float(ds["center_scale"]), spread=float(ds["spread"]))
+    x = _mixture(key, total=total, n_bound=n_bound, n_noise=n_noise, **shape)
     n = int(ds["n_base"])
-    return x[:n], x[n:]
+    qs = ds.get("queries")
+    if qs is None:
+        return x[:n], x[n:], None
+    n_learn = int(qs.get("n_learn", 0))
+    y = _shifted(key, n=n_learn + int(ds["n_queries"]), offset=float(qs["offset"]), **shape)
+    return x[:n], y[n_learn:], (y[:n_learn] if n_learn else None)
 
 
 def fingerprint(x: np.ndarray) -> str:

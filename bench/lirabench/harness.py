@@ -9,6 +9,14 @@ both the index and the traffic) and each metric it reports
 None where it finds nothing to read). Adding a configuration, a mix, a cell
 or a metric is adding files and entries in ``BENCHMARK.json``.
 
+A configuration runs under what it declares and nothing else: its
+``dataset.metric`` (``l2`` or ``ip``) decides the reference's distances and
+the probing copy the work is counted with (``lirabench/probing/<metric>.py``),
+its ``dataset.queries`` the query distribution (``corpus.py``). A metric,
+dtype or query distribution the benchmark does not implement is refused
+before set-up (``check_declared``). A cell's ``chips`` sets the mesh, (1,
+chips) over ("data", "model").
+
 Order of a run:
 
 1. set-up (``setup_s``): the corpus on the device, the index loaded, every
@@ -285,11 +293,17 @@ def open_loop(engine, mix: dict, pool_np: np.ndarray, seed: int, seconds: float)
 
 
 def ground_truth(root: pathlib.Path, cfg: dict, fp: str, pool_np, base, k: int) -> np.ndarray:
-    """Exact k-NN ids of the whole query pool, kept per corpus."""
-    path = root / "bench" / ".cache" / "gt" / cfg["name"] / f"{fp}_k{k}.npy"
+    """Exact k-NN ids of the whole query pool under the configuration's
+    metric, kept per corpus (and per metric and pool, where the pool is not
+    the tail of the corpus's own draw or the metric is not squared L2)."""
+    ds = cfg["dataset"]
+    stem = f"{fp}_k{k}"
+    if ds["metric"] != "l2" or "queries" in ds:
+        stem = f"{fp}_{ds['metric']}_{corpus.fingerprint(pool_np)}_k{k}"
+    path = root / "bench" / ".cache" / "gt" / cfg["name"] / f"{stem}.npy"
     if path.exists():
         return np.load(path)
-    _, ids = reference.knn(pool_np, base, k)
+    _, ids = reference.knn(pool_np, base, k, metric=ds["metric"])
     path.parent.mkdir(parents=True, exist_ok=True)
     np.save(path, ids)
     return ids
@@ -312,11 +326,27 @@ def read_trace(d: pathlib.Path, step_scopes: list) -> dict:
     return trace_reduce.reduce(ex)
 
 
+def check_declared(cfg: dict) -> None:
+    """Refuse a configuration that declares what the benchmark does not
+    implement, naming the key and the value: a metric other than ``l2`` or
+    ``ip``, one with no ``probing/<metric>.py``, a dtype other than float32,
+    an unknown query distribution, an ``index.metric`` other than the
+    dataset's (the metric is declared once, under ``dataset``)."""
+    ds = cfg["dataset"]
+    reference.check_metric(ds.get("metric"))
+    if cfg["index"].get("metric", ds["metric"]) != ds["metric"]:
+        raise ValueError(f"index.metric {cfg['index']['metric']!r} differs from "
+                         f"dataset.metric {ds['metric']!r}")
+    corpus.check(ds)
+    work.probing(ds["metric"])
+
+
 @dataclasses.dataclass
 class Prepared:
     """A cell after set-up: its files, the corpus and the warm engine."""
     cfg: dict
     mix: dict
+    devices: list
     engine: object
     base: object
     base_np: np.ndarray
@@ -328,51 +358,61 @@ class Prepared:
     compiles: CompileCounter
 
 
-def prepare(root: pathlib.Path, bench: dict, cell_name: str, device) -> Prepared:
-    """Set-up: the corpus on the device, the index loaded or built, every
-    serve step the mix can use compiled, one executed."""
+def prepare(root: pathlib.Path, bench: dict, cell_name: str, devices: list) -> Prepared:
+    """Set-up on the cell's first ``chips`` of ``devices``: the corpus on the
+    device, the index loaded or built, every serve step the mix can use
+    compiled, one executed. What the configuration declares is checked
+    first."""
     from jax.sharding import Mesh
 
     cell = find(bench["workloads"], cell_name)
     cfg = load_json(root / find(bench["configs"], cell["config"])["file"])
     mix = load_json(root / "bench" / "traffic" / f"{cell['traffic']}.json")
+    check_declared(cfg)
+    chips = int(cell.get("chips", 1))
+    if len(devices) < chips:
+        raise RuntimeError(f"{cell_name} needs {chips} chips, {len(devices)} given")
+    devices = list(devices[:chips])
     compiles = CompileCounter()
     split: dict = {}
     t_setup = time.perf_counter()
     t = time.perf_counter()
-    base, pool = corpus.make_corpus(cfg["dataset"])
+    base, pool, learn = corpus.make_corpus(cfg["dataset"])
     jax.block_until_ready(base)
     split["data generate"] = time.perf_counter() - t
     t = time.perf_counter()
     base_np, pool_np = np.asarray(base), np.asarray(pool)
     fp = corpus.fingerprint(base_np)
     split["data to host"] = time.perf_counter() - t
-    mesh = Mesh(np.array([device]).reshape(1, 1), ("data", "model"))
-    engine, info = index_cache.load_or_build(root, cfg, base, base_np, mesh, fp,
-                                             device.device_kind, log)
+    mesh = Mesh(np.array(devices).reshape(1, len(devices)), ("data", "model"))
+    engine, info = index_cache.load_or_build(root, cfg, base, base_np, learn, mesh, fp,
+                                             devices[0].device_kind, log)
     split["load read"], split["load place"] = info["read_s"], info["place_s"]
     warm, hlo = warm_up(engine, buckets_for(engine, mix), int(mix["k"]), pool_np)
     split.update(warm)
     # a checkout's one index build is not set-up the runs repeat
     setup_s = time.perf_counter() - t_setup - info.get("build_s", 0.0)
-    return Prepared(cfg, mix, engine, base, base_np, pool_np, fp, split, hlo, setup_s, compiles)
+    return Prepared(cfg, mix, devices, engine, base, base_np, pool_np, fp, split, hlo, setup_s,
+                    compiles)
 
 
 def run_cell(root: pathlib.Path, bench: dict, cell_name: str, seed: int, seconds: float,
-             trace: bool, device, *, fault=None) -> dict:
-    """One run of one cell on ``device``; returns the result object (the
-    last line the benchmark prints). ``fault`` (``faults.Fault``), for the
-    controls only, breaks the timed path underneath."""
+             trace: bool, devices: list, *, fault=None) -> dict:
+    """One run of one cell on the first ``chips`` of ``devices``; returns the
+    result object (the last line the benchmark prints). ``fault``
+    (``faults.Fault``), for the controls only, breaks the timed path
+    underneath."""
     with fault.patch() if fault is not None else contextlib.nullcontext():
-        return _run_cell(root, bench, cell_name, seed, seconds, trace, device, fault)
+        return _run_cell(root, bench, cell_name, seed, seconds, trace, devices, fault)
 
 
-def _run_cell(root, bench, cell_name, seed, seconds, trace, device, fault) -> dict:
-    p = prepare(root, bench, cell_name, device)
+def _run_cell(root, bench, cell_name, seed, seconds, trace, devices, fault) -> dict:
+    p = prepare(root, bench, cell_name, devices)
     if fault is not None:
         fault.engine(p.engine, p.base_np)
-    cfg, mix, engine, base, base_np, pool_np, fp = (p.cfg, p.mix, p.engine, p.base, p.base_np,
-                                                    p.pool_np, p.fp)
+    cfg, mix, devices, engine, base, base_np, pool_np, fp = (
+        p.cfg, p.mix, p.devices, p.engine, p.base, p.base_np, p.pool_np, p.fp)
+    metric = cfg["dataset"]["metric"]
     split, hlo, setup_s, compiles = p.split, p.hlo, p.setup_s, p.compiles
     k = int(mix["k"])
     del p
@@ -392,8 +432,8 @@ def _run_cell(root, bench, cell_name, seed, seconds, trace, device, fault) -> di
         loop = closed_loop if mix["loop"] == "closed" else open_loop
         out = loop(engine, mix, pool_np, seed, seconds)
     window_compiles = (compiles.n - n0, compiles.s - s0)
-    stats = device.memory_stats() or {}
-    peak = int(stats.get("peak_bytes_in_use", 0))
+    stats = [d.memory_stats() or {} for d in devices]
+    peak = max(int(st.get("peak_bytes_in_use", 0)) for st in stats)
 
     # the program's state goes before the reference runs
     params = jax.tree.map(np.asarray, engine.params)
@@ -415,13 +455,14 @@ def _run_cell(root, bench, cell_name, seed, seconds, trace, device, fault) -> di
         log(f"generator lateness: mean {lt.mean() * 1e3:.3f} ms, p95 "
             f"{np.percentile(lt, 95) * 1e3:.3f} ms, max {lt.max() * 1e3:.3f} ms "
             f"over {len(lt)} submits")
-    log(f"device memory: peak_bytes_in_use={peak} bytes_limit={stats.get('bytes_limit')}")
+    log(f"device memory: peak_bytes_in_use={peak} (fullest of {len(devices)}) "
+        f"bytes_limit={stats[0].get('bytes_limit')}")
 
     scan = {"dim": ecfg.dim}
     if ecfg.tier in ("pq", "residual_pq"):
         scan.update(pq_m=ecfg.pq_m, pq_ks=ecfg.pq_ks, residual=ecfg.tier == "residual_pq")
     for j, st in enumerate(ctx.steps):
-        mask = work.probe_mask(params, cents, st.queries, sigma, ecfg.nprobe_max)
+        mask = work.probe_mask(params, cents, st.queries, sigma, ecfg.nprobe_max, metric)
         ctx.work.append(work.step_work(mask, live, scan))
         log(f"step {j}: rows={len(st.queries)} bucket={st.bucket} "
             f"ms={(st.t1 - st.t0) * 1e3:.3f} overflow={st.overflow} "
@@ -431,7 +472,8 @@ def _run_cell(root, bench, cell_name, seed, seconds, trace, device, fault) -> di
     gt = ground_truth(root, cfg, fp, pool_np, base, k)
     answered = len(out["rows"]) > 0
     ctx.recall = reference.recall(out["ids"], gt[out["rows"]]) if answered else 0.0
-    gap = reference.dist_gap(pool_np[out["rows"]], out["ids"], out["dists"], base_np)
+    gap = reference.dist_gap(pool_np[out["rows"]], out["ids"], out["dists"], base_np,
+                             metric=metric)
     bad = reference.bad_answers(out["ids"], out["dists"], len(base_np)) + out["missing"]
     limits = load_json(root / "bench" / "limits" / f"{cell_name}.json")
     checks = {"dist_gap": {"value": gap, "limit": limits["dist_gap"]},
@@ -442,12 +484,12 @@ def _run_cell(root, bench, cell_name, seed, seconds, trace, device, fault) -> di
     ctx.window_s = out["window_s"]
     ctx.attempted, ctx.failed = out["attempted"], out["failed"]
     ctx.latencies_ms = out["latencies_ms"]
-    result_device = {"platform": device.platform, "kind": device.device_kind,
+    result_device = {"platform": devices[0].platform, "kind": devices[0].device_kind,
                      "count": len(jax.devices()),
                      "memory_peak_bytes": peak}
     if trace:
         ctx.trace = read_trace(tdir, [hlo[st.bucket] for st in ctx.steps])
-        ctx.peaks = work.peaks(root / "bench", device.device_kind)
+        ctx.peaks = work.peaks(root / "bench", devices[0].device_kind)
         result_device.update(busy_s=ctx.trace["busy_s"], window_s=ctx.trace["window_s"])
         log(f"trace: window {ctx.trace['window_s']:.6f} s, busy {ctx.trace['busy_s']:.6f} s, "
             f"by scope {json.dumps(ctx.trace['by_scope'])}, by kernel "

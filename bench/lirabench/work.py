@@ -7,7 +7,8 @@ skips empty buckets or padding does the same work in less time and cannot
 pass 100%:
 
 * which partitions each query probes: the probing model (copied from the
-  paper's equations, ``core/probing.py``) over the built index's parameters
+  paper's equations, ``core/probing.py``, into ``probing/<metric>.py`` for
+  the configuration's ``dataset.metric``) over the built index's parameters
   and centroids, top ``nprobe_max`` by probability, those above ``sigma``,
   the best always;
 * bytes: the live slots of every partition at least one query of the step
@@ -22,14 +23,16 @@ over the chip's fastest arithmetic (bf16 on the MXU), from ``peaks.json``.
 """
 from __future__ import annotations
 
+import functools
+import importlib.util
 import json
 import pathlib
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
-_HIGHEST = jax.lax.Precision.HIGHEST
+
+PROBING_DIR = pathlib.Path(__file__).parent / "probing"
 
 
 def peaks(bench_dir: pathlib.Path, device_kind: str) -> dict:
@@ -39,30 +42,28 @@ def peaks(bench_dir: pathlib.Path, device_kind: str) -> dict:
     return table[device_kind]
 
 
-def _mlp(layers, x, final_act=True):
-    for i, layer in enumerate(layers):
-        x = jnp.dot(x, layer["w"], precision=_HIGHEST) + layer["b"]
-        if final_act or i + 1 < len(layers):
-            x = jax.nn.relu(x)
-    return x
+@functools.cache
+def probing(metric: str):
+    """The benchmark's copy of the probing model under ``metric``:
+    ``probing/<metric>.py``, whose ``probs(params, cents, q)`` gives [nq, B]
+    probabilities. Loaded once per process, as a metric reader is found."""
+    path = PROBING_DIR / f"{metric}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"dataset.metric {metric!r} has no probing model: "
+                                f"{path} is missing")
+    spec = importlib.util.spec_from_file_location(f"lirabench_probing_{metric}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
-@jax.jit
-def _probs(params, cents, q):
-    cd = (jnp.sum(q * q, -1, keepdims=True) - 2.0 * jnp.dot(q, cents.T, precision=_HIGHEST)
-          + jnp.sum(cents * cents, -1)[None, :])
-    qn = q / (jnp.linalg.norm(q, axis=-1, keepdims=True) + 1e-6)
-    feat = cd / (jnp.mean(cd, axis=-1, keepdims=True) + 1e-6) - 1.0
-    x = jnp.concatenate([_mlp(params["phi_q"], qn), _mlp(params["phi_i"], feat)], -1)
-    return jax.nn.sigmoid(_mlp(params["phi_p"], x, final_act=False))
-
-
-def probe_mask(params, cents, q: np.ndarray, sigma: float, nprobe_max: int) -> np.ndarray:
+def probe_mask(params, cents, q: np.ndarray, sigma: float, nprobe_max: int,
+               metric: str) -> np.ndarray:
     """[nq, B] bool: the partitions each query probes."""
     n = len(q)
     pad = np.zeros((max(8, 1 << (n - 1).bit_length()), q.shape[1]), np.float32)
     pad[:n] = q          # a few padded shapes, not one program per batch size
-    p = np.asarray(_probs(params, cents, jnp.asarray(pad)))[:n]
+    p = np.asarray(probing(metric).probs(params, cents, jnp.asarray(pad)))[:n]
     top = np.argsort(-p, axis=1, kind="stable")[:, :nprobe_max]
     keep = np.take_along_axis(p, top, 1) > sigma
     keep[:, 0] = True

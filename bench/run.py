@@ -3,7 +3,8 @@
 
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
-Runs on the machine it is started on, on its first TPU. The last line of
+Runs on the machine it is started on, on as many of its TPUs as the cell's
+``chips`` says. The last line of
 standard output is the result (``correct``, ``attempted``, ``failed``,
 ``metrics``, ``device``, with ``--trace 1`` also ``breakdown``, and last
 ``checks``: each number compared beside its limit); the lines before it
@@ -53,7 +54,7 @@ def main() -> int:
         return 1
     work.peaks(ROOT / "bench", devices[0].device_kind)     # an unknown kind is an error
     result = harness.run_cell(ROOT, bench, args.workload, args.seed, args.seconds,
-                              bool(args.trace), devices[0])
+                              bool(args.trace), devices)
     harness.report(result)
     return 0
 

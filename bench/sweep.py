@@ -42,11 +42,11 @@ def main() -> int:
     from lirabench import harness
 
     harness.enable_compile_cache(ROOT)
-    device = jax.devices()[0]
-    if device.platform != "tpu":
+    devices = jax.devices()
+    if devices[0].platform != "tpu":
         print("sweep.py: no TPU", file=sys.stderr)
         return 1
-    p = harness.prepare(ROOT, bench, args.workload, device)
+    p = harness.prepare(ROOT, bench, args.workload, devices)
     harness.log(f"setup: {p.setup_s:.3f} s")
     steps: list = []
     harness.instrument(p.engine, steps)

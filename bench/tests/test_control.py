@@ -20,7 +20,7 @@ ROOT = pathlib.Path(__file__).resolve().parents[2]
 def data():
     cfg = json.loads((ROOT / "bench/configs/sift1m-f32.json").read_text())
     ds = dict(cfg["dataset"], n_base=20_000, n_queries=400)
-    base, pool = corpus.make_corpus(ds)
+    base, pool, _ = corpus.make_corpus(ds)
     return base, np.asarray(base), np.asarray(pool)
 
 
@@ -31,7 +31,7 @@ def test_control_fails_and_highest_passes(data, cell, traffic):
     mix = json.loads((ROOT / f"bench/traffic/{traffic}.json").read_text())
     limits = json.loads((ROOT / f"bench/limits/{cell}.json").read_text())
     for seed in (1, 2, 3):
-        r = control.readings(mix, seed, 200, base, base_np, pool_np)
+        r = control.readings(mix, seed, 200, base, base_np, pool_np, "l2")
         low, high = r["control"], r["highest"]
         assert any(low[k] > v for k, v in limits.items()), (seed, low, limits)
         assert all(high[k] <= v for k, v in limits.items()), (seed, high, limits)

@@ -78,7 +78,7 @@ def tiny(tmp_path_factory):
                          ids=["sound", *faults.FAULTS])
 def test_broken_timed_path_is_not_correct(tiny, cell, fault):
     root, bench = tiny
-    res = harness.run_cell(root, bench, cell, 2**31 + 12345, 1.0, False, jax.devices()[0],
+    res = harness.run_cell(root, bench, cell, 2**31 + 12345, 1.0, False, jax.devices(),
                            fault=faults.FAULTS[fault]() if fault else None)
     assert res["correct"] is (fault is None), res["checks"]
     assert list(res)[-1] == "checks"
